@@ -24,8 +24,8 @@ import time
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.core.hierarchical import make_exchange_fns
-from repro.compat import make_mesh
-mesh = make_mesh((2, 4), ("pod", "data"))
+from jax.sharding import AxisType
+mesh = jax.make_mesh((2, 4), ("pod", "data"), axis_types=(AxisType.Auto,) * 2)
 n_dev, chunk, d = 8, 64, 256
 x = jnp.arange(n_dev * n_dev * chunk * d, dtype=jnp.float32).reshape(
     n_dev, n_dev, chunk, d)
@@ -66,17 +66,19 @@ def main(argv=None):
 
     if not args.skip_exec:
         env = dict(os.environ)
+        # fake host devices only: the child never touches an accelerator
+        # the parent process may hold
+        env["JAX_PLATFORMS"] = "cpu"
         env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         env["PYTHONPATH"] = env.get("PYTHONPATH", "src")
         out = subprocess.run(
             [sys.executable, "-c", _CHILD], env=env, capture_output=True, text=True
         )
         if out.returncode != 0:
-            emit("a2a/exec_equal", 0, out.stderr.strip()[-200:])
-        else:
-            for line in out.stdout.strip().splitlines():
-                k, v = line.split(",")
-                emit(f"a2a/exec_{k}_us" if k != "equal" else "a2a/exec_equal", v, "")
+            raise SystemExit(f"8-device a2a run failed:\n{out.stderr[-3000:]}")
+        for line in out.stdout.strip().splitlines():
+            k, v = line.split(",")
+            emit(f"a2a/exec_{k}_us" if k != "equal" else "a2a/exec_equal", v, "")
 
 
 if __name__ == "__main__":

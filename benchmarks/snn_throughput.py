@@ -30,7 +30,7 @@ from benchmarks.common import emit
 _CHILD = r"""
 import sys, time
 import numpy as np, jax, jax.numpy as jnp
-from repro.compat import make_mesh
+from jax.sharding import AxisType
 from repro.snn import DistributedSNN, LIFParams, expand_synapses_sparse, generate_brain_model
 
 n_pop, n_reg, npp, steps = (int(a) for a in sys.argv[1:5])
@@ -38,7 +38,7 @@ bm = generate_brain_model(n_populations=n_pop, n_regions=n_reg,
                           total_neurons=10**7, seed=0)
 syn, _ = expand_synapses_sparse(bm.graph, npp, 8, seed=0)
 params = LIFParams(noise_sigma=0.0)
-mesh = make_mesh((4, 2), ("pod", "data"))
+mesh = jax.make_mesh((4, 2), ("pod", "data"), axis_types=(AxisType.Auto,) * 2)
 engines = {
     "flat": DistributedSNN(mesh=mesh, w_syn=jnp.asarray(syn.to_dense()),
                            params=params, exchange="flat", i_ext=4.0),
@@ -122,6 +122,9 @@ def main(argv=None):
 
     if not args.skip_exec:
         env = dict(os.environ)
+        # fake host devices only: the child never touches an accelerator
+        # the parent process may hold
+        env["JAX_PLATFORMS"] = "cpu"
         env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         env["PYTHONPATH"] = env.get("PYTHONPATH", "src")
         out = subprocess.run(
@@ -139,12 +142,10 @@ def main(argv=None):
             text=True,
         )
         if out.returncode != 0:
-            err = out.stderr.strip().splitlines() or ["unknown error"]
-            emit("snn/exec_rasters_equal", 0, err[-1][:200])
-        else:
-            for line in out.stdout.strip().splitlines():
-                k, v = line.split(",")
-                emit(f"snn/exec_{k}", v, "8 host devices")
+            raise SystemExit(f"8-device SNN run failed:\n{out.stderr[-3000:]}")
+        for line in out.stdout.strip().splitlines():
+            k, v = line.split(",")
+            emit(f"snn/exec_{k}", v, "8 host devices")
 
 
 if __name__ == "__main__":

@@ -82,9 +82,9 @@ def main():
     perm = partition_permutation(n_assign_eq, n_dev)
     wp = w[np.ix_(perm, perm)].astype(np.float32) * 0.05
 
-    from repro.compat import make_mesh
+    from jax.sharding import AxisType
 
-    mesh = make_mesh((2, 4), ("pod", "data"))
+    mesh = jax.make_mesh((2, 4), ("pod", "data"), axis_types=(AxisType.Auto,) * 2)
     rasters = {}
     for exchange in ("flat", "two_level"):
         eng = DistributedSNN(

@@ -34,7 +34,7 @@ import jax
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.compat import shard_map
+from jax import shard_map
 
 __all__ = [
     "flat_all_to_all",

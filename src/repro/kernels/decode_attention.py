@@ -23,7 +23,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-from repro.compat import pallas_tpu_compiler_params
 
 __all__ = ["decode_attention"]
 
@@ -152,7 +151,7 @@ def decode_attention(
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((b, hq, 1, d), q.dtype),
-        compiler_params=pallas_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

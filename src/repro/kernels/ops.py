@@ -41,14 +41,15 @@ __all__ = [
 class KernelPolicy:
     """How the model zoo executes its hot-spots.
 
-    use_pallas: run Pallas kernels (with ``interpret`` on CPU) instead of
-      the jnp reference path.  The dry-run keeps this False so XLA's
-      cost model sees the true FLOPs (DESIGN.md §7).
-    interpret: Pallas interpret mode (always True on CPU).
+    use_pallas: run Pallas kernels instead of the jnp reference path.
+      The dry-run keeps this False so XLA's cost model sees the true
+      FLOPs.
+    interpret: Pallas interpret mode.  Off by default so the kernels
+      compile for the chip; CPU callers (the tests) pass True.
     """
 
     use_pallas: bool = False
-    interpret: bool = True
+    interpret: bool = False
 
 
 def attention(

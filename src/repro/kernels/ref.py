@@ -138,16 +138,23 @@ def rglru_ref(a: jax.Array, b: jax.Array) -> jax.Array:
 
 
 def spike_accum_ref(spikes: jax.Array, w: jax.Array) -> jax.Array:
-    """I = s @ W."""
-    return (spikes.astype(jnp.float32) @ w.astype(jnp.float32)).astype(jnp.float32)
+    """I = s @ W (f32, ``Precision.HIGHEST``)."""
+    return jnp.matmul(
+        spikes.astype(jnp.float32),
+        w.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST,
+    )
 
 
 def spike_accum_blocks_ref(
     s_blocks: jax.Array, src_ids: jax.Array, blocks: jax.Array
 ) -> jax.Array:
-    """Block-CSR accumulation: ``I = Σ_k s_blocks[src_ids[k]] @ blocks[k]``."""
+    """Block-CSR accumulation: ``I = Σ_k s_blocks[src_ids[k]] @ blocks[k]``
+    (f32, ``Precision.HIGHEST``, as in the Pallas kernel, whose default on
+    a TPU is a single bf16 pass: both compute the same on every backend)."""
     sel = s_blocks.astype(jnp.float32)[src_ids]  # [K, B]
     return jnp.einsum(
         "kb,kbj->j", sel, blocks.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32,
     )

@@ -24,7 +24,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-from repro.compat import pallas_tpu_compiler_params
 
 __all__ = ["rglru_scan"]
 
@@ -87,7 +86,7 @@ def rglru_scan(
         out_specs=pl.BlockSpec((1, chunk, block_d), lambda b_, id_, ic: (b_, ic, id_)),
         out_shape=jax.ShapeDtypeStruct(a.shape, a.dtype),
         scratch_shapes=[pltpu.VMEM((1, block_d), jnp.float32)],
-        compiler_params=pallas_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

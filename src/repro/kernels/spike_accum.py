@@ -7,17 +7,19 @@ vector (sparse: biological firing rates mean ~1% of entries are 1) and
 
 GPU simulators implement this with scatter-atomics over the spike list.
 That mechanism has no TPU analogue (no atomics; registers are vector
-lanes) — the TPU-native adaptation (DESIGN.md §7) is a **block-masked
-dense matmul**: tile ``W`` into MXU-aligned VMEM blocks, check each
-spike block with a cheap VPU reduction, and *skip the MXU work and the
-HBM→VMEM fetch of W* for blocks with no spikes.  At 1% firing the
-expected skip rate per 128-row block is ``0.99^128 ≈ 28%``, and the
-win grows for the synchronized-burst regimes brain models exhibit
-(most blocks silent between population bursts).
+lanes) — the TPU-native adaptation is a **block-masked dense matmul**:
+tile ``W`` into MXU-aligned VMEM blocks, check each spike block with a
+cheap VPU reduction, and skip the MXU work for blocks with no spikes.
+The pipeline still copies every ``W`` tile from HBM, whatever the
+firing, so the weight stream bounds both kernels.
 
-Grid: ``(n_j_blocks, n_i_blocks)`` — the ``i`` (reduction) dimension is
-innermost/sequential so a VMEM scratch accumulator carries partial sums;
-the output block is written once on the last ``i`` step.
+Grid of :func:`spike_accum`: ``(n_j_blocks, n_i_blocks)`` — the ``i``
+(reduction) dimension is innermost/sequential so a VMEM scratch
+accumulator carries partial sums; the output block is written once on
+the last ``i`` step.  Both kernels multiply at ``Precision.HIGHEST``, as
+the jnp oracles in :mod:`repro.kernels.ref` do: an f32 ``dot_general``
+in a TPU kernel otherwise runs one bf16 pass (about 1e-3 relative error
+against float64, where HIGHEST stays near 1e-7).
 """
 from __future__ import annotations
 
@@ -27,8 +29,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
-
-from repro.compat import pallas_tpu_compiler_params
 
 __all__ = ["spike_accum", "spike_accum_blocks"]
 
@@ -50,6 +50,7 @@ def _kernel(s_ref, w_ref, out_ref, acc_ref, *, n_i_blocks: int):
             s,
             w,
             (((1,), (0,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
             preferred_element_type=jnp.float32,
         )
 
@@ -98,7 +99,7 @@ def spike_accum(
         out_specs=pl.BlockSpec((1, block_j), lambda j, i: (0, j)),
         out_shape=jax.ShapeDtypeStruct((1, n), jnp.float32),
         scratch_shapes=[pltpu.VMEM((1, block_j), jnp.float32)],
-        compiler_params=pallas_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -106,25 +107,46 @@ def spike_accum(
     return out[0]
 
 
-def _blocks_kernel(src_ref, s_ref, w_ref, out_ref, acc_ref, *, n_k: int):
-    k = pl.program_id(0)
+#: upper bound on a sub-tile side of :func:`spike_accum_blocks` (a 512²
+#: f32 sub-tile is 1 MiB of VMEM, 2 MiB double-buffered)
+_TILE = 512
 
-    @pl.when(k == 0)
+
+def _tile(b: int) -> int:
+    """Largest multiple of 128 that divides ``b`` and is at most
+    :data:`_TILE`; ``b`` itself when ``b <= _TILE`` (a block spanning the
+    whole dimension is legal at any size)."""
+    if b <= _TILE:
+        return b
+    for t in range(_TILE, 127, -128):
+        if b % t == 0:
+            return t
+    raise ValueError(
+        f"block size B={b} has no divisor that is a multiple of 128 and at "
+        f"most {_TILE}; choose a B that has one (no padding is applied)"
+    )
+
+
+def _blocks_kernel(src_ref, s_ref, w_ref, out_ref, acc_ref, *, n_k: int, n_i: int):
+    k, i = pl.program_id(1), pl.program_id(2)
+
+    @pl.when((k == 0) & (i == 0))
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    s = s_ref[...]  # [1, B] — the spike block src_ids[k] (scalar-prefetch DMA)
-    # skip both silent source blocks and zero padding tiles
+    s = s_ref[0]  # [1, ti] — rows i of spike block src_ids[k] (scalar prefetch)
+    # skip the MXU work for silent source rows and zero padding tiles
     @pl.when(jnp.any(s > 0.0))
     def _accumulate():
         acc_ref[...] += jax.lax.dot_general(
             s,
             w_ref[0],
             (((1,), (0,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
             preferred_element_type=jnp.float32,
         )
 
-    @pl.when(k == n_k - 1)
+    @pl.when((k == n_k - 1) & (i == n_i - 1))
     def _flush():
         out_ref[...] = acc_ref[...].astype(out_ref.dtype)
 
@@ -140,15 +162,23 @@ def spike_accum_blocks(
     """Block-CSR synaptic accumulation — the ``'sparse'``/``'ragged'``
     engine's hot-spot, wired into ``DistributedSNN`` behind
     ``KernelPolicy`` (``policy=KernelPolicy(use_pallas=True)`` flips the
-    engine's einsum to this kernel; interpret mode on CPU).
+    engine's einsum to this kernel).
 
     Computes ``I = Σ_k s_blocks[src_ids[k]] @ blocks[k]`` for one device's
     stored incoming tiles (:meth:`repro.snn.sparse.BlockSynapses.padded`
-    layout, zero padding tiles allowed).  ``src_ids`` is scalar-prefetched
-    so each grid step DMAs exactly the spike block its tile consumes —
-    HBM traffic is O(nnzb · B), never O(M); the per-tile VPU check also
-    skips the MXU work for silent source blocks (same trick as
-    :func:`spike_accum`).
+    layout, zero padding tiles allowed).  Each ``B × Bj`` tile is streamed
+    through VMEM as ``ti × tj`` sub-tiles (:func:`_tile`: the largest
+    multiple of 128 up to 512 that divides the side, or the whole side
+    when it is at most 512), so neither ``B`` nor the tile count is
+    bounded by VMEM.  Grid: ``(Bj/tj, K, B/ti)`` — output column
+    tile ``j`` outermost (parallel), then stored tile ``k`` and row tile
+    ``i`` (sequential, accumulating into a VMEM scratch that is zeroed at
+    the first ``(k, i)`` of each ``j`` and flushed at the last).
+    ``src_ids`` is scalar-prefetched, so each grid step DMAs exactly the
+    ``ti`` spike lanes its sub-tile consumes; a VPU check skips the MXU
+    work for silent rows (the weight sub-tile is still fetched).  The
+    matmul runs at ``Precision.HIGHEST`` (f32), the same as the einsum
+    oracle.
 
     Args:
       s_blocks: ``f32[n_blocks, B]`` global spike vector, one row per
@@ -168,23 +198,25 @@ def spike_accum_blocks(
         )
     if k == 0:  # no tiles → no currents (a zero-size grid cannot run)
         return jnp.zeros((bj,), jnp.float32)
+    ti, tj = _tile(b), _tile(bj)
+    n_i, n_j = b // ti, bj // tj
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(k,),
+        grid=(n_j, k, n_i),
         in_specs=[
-            pl.BlockSpec((1, b), lambda i, src: (src[i], 0)),
-            pl.BlockSpec((1, bi, bj), lambda i, src: (i, 0, 0)),
+            pl.BlockSpec((1, 1, ti), lambda j, kk, i, src: (src[kk], 0, i)),
+            pl.BlockSpec((1, ti, tj), lambda j, kk, i, src: (kk, i, j)),
         ],
-        out_specs=pl.BlockSpec((1, bj), lambda i, src: (0, 0)),
-        scratch_shapes=[pltpu.VMEM((1, bj), jnp.float32)],
+        out_specs=pl.BlockSpec((1, tj), lambda j, kk, i, src: (0, j)),
+        scratch_shapes=[pltpu.VMEM((1, tj), jnp.float32)],
     )
     out = pl.pallas_call(
-        functools.partial(_blocks_kernel, n_k=k),
+        functools.partial(_blocks_kernel, n_k=k, n_i=n_i),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((1, bj), jnp.float32),
-        compiler_params=pallas_tpu_compiler_params(
-            dimension_semantics=("arbitrary",),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary", "arbitrary"),
         ),
         interpret=interpret,
-    )(src_ids.astype(jnp.int32), s_blocks, blocks)
+    )(src_ids.astype(jnp.int32), s_blocks.reshape(n_blocks, 1, b), blocks)
     return out[0]

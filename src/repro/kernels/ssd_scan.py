@@ -28,7 +28,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-from repro.compat import pallas_tpu_compiler_params
 
 __all__ = ["ssd_scan"]
 
@@ -124,7 +123,7 @@ def ssd_scan(
         out_specs=pl.BlockSpec((1, chunk, 1, p), lambda b_, h_, ic: (b_, ic, h_, 0)),
         out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
         scratch_shapes=[pltpu.VMEM((n, p), jnp.float32)],
-        compiler_params=pallas_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -48,9 +48,11 @@ def main():
         cfg = cfg.reduced()
     n_dev = jax.device_count()
     if n_dev > 1:
-        from repro.compat import make_mesh
+        from jax.sharding import AxisType
 
-        mesh = make_mesh((n_dev // 2, 2), ("data", "model"))
+        mesh = jax.make_mesh(
+            (n_dev // 2, 2), ("data", "model"), axis_types=(AxisType.Auto,) * 2
+        )
         pol = make_policy(mesh)
     else:
         pol = ShardingPolicy()

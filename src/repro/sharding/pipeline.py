@@ -22,7 +22,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.compat import shard_map
+from jax import shard_map
 
 __all__ = ["gpipe", "bubble_fraction"]
 

@@ -48,13 +48,13 @@ import time
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-from repro.compat import shard_map
 
 from repro.core.routing import pool_block_mask
 from repro.obs import trace as obs
 from repro.kernels.ops import KernelPolicy, spike_currents_blocks
+from repro.kernels.ref import spike_accum_ref
 from repro.snn.ragged import RaggedPlan, build_ragged_plan
 from repro.snn.sparse import BlockSynapses, exchange_schedule, exchange_volume
 from repro.snn.neuron import (
@@ -131,7 +131,7 @@ class DistributedSNN:
         the device count.
       policy: how the block-CSR accumulation hot-spot executes — the jnp
         einsum oracle (default) or the Pallas ``spike_accum_blocks``
-        kernel (``KernelPolicy(use_pallas=True)``; keep
+        kernel (``KernelPolicy(use_pallas=True)``; add
         ``interpret=True`` on CPU).
       bridge_inner: ``int[G, G]`` inner mesh index of each group's bridge
         device per destination group (``exchange='ragged'``); ``None``
@@ -317,7 +317,7 @@ class DistributedSNN:
             def body(carry, _):
                 state, prev_loc = carry
                 s_global = gather(prev_loc)
-                i_syn = s_global @ w_block + i_ext
+                i_syn = spike_accum_ref(s_global, w_block) + i_ext
                 state, spikes = step(state, i_syn, params)
                 return (state, spikes), spikes
 
@@ -350,10 +350,11 @@ class DistributedSNN:
         each boundary — *blocked* timings, so a phase's number is wall
         time until its results exist, not dispatch time:
 
-        * ``prepare_s`` — building/looking up the compiled step and
-          staging its device inputs (``_sparse_callable_and_args``);
-        * ``first_call_s`` — first execution, compile included;
-        * ``steady_call_s`` — second execution (compile-cache warm);
+        * ``prepare_s`` — :meth:`compile` as a whole: building/looking up
+          the step, staging its device inputs, lowering and compiling;
+        * ``compile_s`` — the lowering and compiling within it;
+        * ``first_call_s`` — first execution;
+        * ``steady_call_s`` — second execution;
 
         plus the engine's :meth:`exchange_stats` byte ledger
         (``bytes_per_step``, chosen exchange) and the process-wide
@@ -363,22 +364,21 @@ class DistributedSNN:
         """
         if self.exchange not in ("sparse", "ragged"):
             raise ValueError("step_profile covers exchange='sparse'/'ragged'")
-        key = jax.random.PRNGKey(0) if key is None else key
         prof: dict[str, float] = {}
         with obs.span("snn.step_profile", cat="exec", tid="snn",
                       args={"exchange": self.exchange, "n_steps": n_steps}):
             t = time.perf_counter()
             with obs.span("snn.prepare", cat="exec", tid="snn"):
-                fn, args = self._sparse_callable_and_args(n_steps, key=key)
+                compiled, args, prof["compile_s"] = self.compile(n_steps, key=key)
                 jax.block_until_ready(args)
             prof["prepare_s"] = time.perf_counter() - t
             t = time.perf_counter()
             with obs.span("snn.first_call", cat="exec", tid="snn"):
-                jax.block_until_ready(fn(*args))
+                jax.block_until_ready(compiled(*args))
             prof["first_call_s"] = time.perf_counter() - t
             t = time.perf_counter()
             with obs.span("snn.steady_call", cat="exec", tid="snn"):
-                jax.block_until_ready(fn(*args))
+                jax.block_until_ready(compiled(*args))
             prof["steady_call_s"] = time.perf_counter() - t
         stats = self.exchange_stats()
         bytes_step = float(stats[self.exchange])
@@ -390,6 +390,25 @@ class DistributedSNN:
         prof["step_cache_hits"] = float(ci.hits)
         prof["step_cache_misses"] = float(ci.misses)
         return prof
+
+    def compile(
+        self, n_steps: int, *, key: jax.Array | None = None
+    ) -> tuple[jax.stages.Compiled, tuple, float]:
+        """Stage the sparse/ragged run's inputs and compile its step ahead
+        of time.
+
+        Returns ``(compiled, args, compile_s)``: ``compiled(*args)`` runs
+        the ``n_steps`` simulation and returns the raster ``[T, M]``, as
+        :meth:`run` does; ``compile_s`` is the wall time of lowering and
+        compiling alone (input staging excluded).
+        """
+        if self.exchange not in ("sparse", "ragged"):
+            raise ValueError("compile covers exchange='sparse'/'ragged'")
+        key = jax.random.PRNGKey(0) if key is None else key
+        fn, args = self._sparse_callable_and_args(n_steps, key=key)
+        t = time.perf_counter()
+        compiled = fn.lower(*args).compile()
+        return compiled, args, time.perf_counter() - t
 
     def _step_key(self, n_steps: int) -> "_StepKey":
         return _StepKey(
@@ -412,8 +431,9 @@ class DistributedSNN:
         *inputs* — neuron state, padded synapse tiles, and the per-round
         spike index rows.  Swapping to a plan with an equal
         :meth:`step_signature` therefore reuses the compiled step.
-        Shared by :meth:`run` (executes) and :meth:`trace_step`
-        (abstractly traces — planlint Layer 2).
+        Shared by :meth:`run` (executes), :meth:`compile` (compiles
+        ahead of time) and :meth:`trace_step` (abstractly traces —
+        planlint Layer 2).
         """
         syn = self._block_synapses()
         n_dev = self.n_devices
@@ -445,8 +465,10 @@ class DistributedSNN:
         u0 = jax.device_put(st0.u, sharding)
         keys = jax.device_put(keys, sharding)
         blk_sharding = NamedSharding(self.mesh, vec_spec)
-        src_arr = jax.device_put(jnp.asarray(src_pad), blk_sharding)
-        blk_arr = jax.device_put(jnp.asarray(blk_pad), blk_sharding)
+        # straight from the host to each device's shard (no staging of the
+        # whole tile array on the first device)
+        src_arr = jax.device_put(src_pad.astype(np.int32), blk_sharding)
+        blk_arr = jax.device_put(blk_pad, blk_sharding)
         idx_put = tuple(jax.device_put(a, blk_sharding) for a in idx_arrays)
         return fn, (v0, u0, keys, src_arr, blk_arr, idx_put)
 

@@ -20,6 +20,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.graph import CommGraph
+from repro.kernels.ref import spike_accum_ref
 from repro.snn.sparse import BlockSynapses
 from repro.snn.neuron import (
     IzhikevichParams,
@@ -228,24 +229,22 @@ class SNNEngine:
         step = self._step_fn()
         w = self.w_syn
         i_ext = jnp.asarray(self.i_ext, dtype=jnp.float32)
-        accumulate = (
-            current_fn
-            if current_fn is not None
-            else lambda spikes, w_syn: spikes @ w_syn
-        )
-
-        def body(carry, _):
-            state, prev_spikes = carry
-            i_syn = accumulate(prev_spikes, w) + i_ext
-            state, spikes = step(state, i_syn, self.params)
-            out = (spikes, state.v if record_v else jnp.zeros((0,), jnp.float32))
-            return (state, spikes), out
+        accumulate = current_fn if current_fn is not None else spike_accum_ref
 
         init = (state0, jnp.zeros((self.n_neurons,), jnp.float32))
 
+        # W is an argument, not a closed-over constant: XLA would otherwise
+        # embed the whole [M, M] matrix in the program it compiles
         @jax.jit
-        def _run(init):
+        def _run(init, w):
+            def body(carry, _):
+                state, prev_spikes = carry
+                i_syn = accumulate(prev_spikes, w) + i_ext
+                state, spikes = step(state, i_syn, self.params)
+                out = (spikes, state.v if record_v else jnp.zeros((0,), jnp.float32))
+                return (state, spikes), out
+
             return jax.lax.scan(body, init, None, length=n_steps)
 
-        (final_state, _), (spikes, vs) = _run(init)
+        (final_state, _), (spikes, vs) = _run(init, w)
         return RunResult(spikes=spikes, v_trace=vs, final_state=final_state)

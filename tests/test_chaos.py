@@ -83,7 +83,7 @@ class TestSchedule:
 class TestInjectorDeterminism:
     @given(seed=st.integers(0, 20))
     @settings(max_examples=10, deadline=None)
-    def test_same_schedule_same_injected_trace(self, seed, tmp_path):
+    def test_same_schedule_same_injected_trace(self, seed):
         """One schedule, two independent derivations of every injector:
         the supervisor hook's fired trace and the netsim outage records
         must be identical — the layers cannot drift apart."""

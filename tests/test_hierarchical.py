@@ -17,8 +17,8 @@ def test_two_level_equals_flat_a2a():
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.core.hierarchical import make_exchange_fns
-from repro.compat import make_mesh
-mesh = make_mesh((2, 4), ("pod", "data"))
+from jax.sharding import AxisType
+mesh = jax.make_mesh((2, 4), ("pod", "data"), axis_types=(AxisType.Auto,) * 2)
 n_dev, chunk, d = 8, 3, 5
 x = jnp.arange(n_dev*n_dev*chunk*d, dtype=jnp.float32).reshape(n_dev, n_dev, chunk, d)
 x = jax.device_put(x, NamedSharding(mesh, P(("pod","data"))))
@@ -37,9 +37,10 @@ def test_hierarchical_psum_equals_flat():
 import functools
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
-from repro.compat import make_mesh, shard_map
+from jax import shard_map
+from jax.sharding import AxisType
 from repro.core.hierarchical import hierarchical_psum, flat_psum, two_level_all_gather
-mesh = make_mesh((2, 4), ("pod", "data"))
+mesh = jax.make_mesh((2, 4), ("pod", "data"), axis_types=(AxisType.Auto,) * 2)
 g = jnp.arange(16*4, dtype=jnp.float32).reshape(16, 4)
 wrap = lambda f: jax.jit(functools.partial(
     shard_map, mesh=mesh, in_specs=(P(),), out_specs=P(), check_vma=False)(f))

@@ -10,7 +10,7 @@ from repro.kernels import ref as R
 from repro.kernels.decode_attention import decode_attention
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.rglru_scan import rglru_scan
-from repro.kernels.spike_accum import spike_accum
+from repro.kernels.spike_accum import spike_accum, spike_accum_blocks
 from repro.kernels.ssd_scan import ssd_scan
 
 RNG = np.random.default_rng(0)
@@ -127,3 +127,41 @@ def test_spike_accum_weighted_spikes():
     w = rng.normal(size=(256, 128)).astype(np.float32)
     out = spike_accum(jnp.asarray(s), jnp.asarray(w), block_i=128, block_j=128, interpret=True)
     np.testing.assert_allclose(np.asarray(out), s @ w, rtol=1e-4, atol=1e-4)
+
+
+@pytest.mark.parametrize(
+    "n_blocks,b,bj,k",
+    [
+        (2, 1024, 1024, 4),  # 2 × 2 sub-tiles of 512, K > n_blocks
+        (3, 1536, 640, 3),  # 3 row tiles of 512; 5 column tiles of 128
+        (4, 1280, 384, 5),  # 5 row tiles of 256; one whole-width column tile
+        (5, 96, 64, 3),  # B below the bound: whole-dimension tiles
+    ],
+)
+def test_spike_accum_blocks_vs_float64(n_blocks, b, bj, k):
+    """The re-tiled block-CSR kernel against float64 ``Σ_k s[src_k] @ W_k``,
+    with silent source blocks, silent row tiles inside a firing block,
+    repeated sources and an all-zero padding tile."""
+    rng = np.random.default_rng(n_blocks * b + k)
+    s = (rng.random((n_blocks, b)) < 0.05).astype(np.float32)
+    s[1] = 0.0  # a silent source block
+    s[0, : min(b, 256)] = 0.0  # a silent row tile inside a firing block
+    src = rng.integers(0, n_blocks, size=k)
+    src[0] = 1  # at least one tile reads the silent block
+    w = rng.normal(size=(k, b, bj)).astype(np.float32)
+    w[-1] = 0.0  # zero padding tile (padded() layout)
+    out = spike_accum_blocks(
+        jnp.asarray(s), jnp.asarray(src), jnp.asarray(w), interpret=True
+    )
+    ref = np.einsum("kb,kbj->j", s.astype(np.float64)[src], w.astype(np.float64))
+    np.testing.assert_allclose(np.asarray(out), ref, rtol=1e-5, atol=1e-4)
+
+
+def test_spike_accum_blocks_rejects_untileable_b():
+    """A B above the tile bound with no multiple-of-128 divisor is refused,
+    never silently padded."""
+    with pytest.raises(ValueError, match="multiple of 128"):
+        spike_accum_blocks(
+            jnp.zeros((2, 600)), jnp.array([0, 1]), jnp.zeros((2, 600, 128)),
+            interpret=True,
+        )

@@ -15,8 +15,8 @@ def test_gpipe_matches_sequential():
     code = """
 import jax, jax.numpy as jnp, numpy as np
 from repro.sharding.pipeline import gpipe
-from repro.compat import make_mesh
-mesh = make_mesh((4,), ("pipe",))
+from jax.sharding import AxisType
+mesh = jax.make_mesh((4,), ("pipe",), axis_types=(AxisType.Auto,))
 n_stages, d, B, mb = 4, 16, 8, 4
 key = jax.random.PRNGKey(0)
 w = jax.random.normal(key, (n_stages, d, d)) * 0.3

@@ -401,7 +401,8 @@ def test_traced_collective_counts_pinned():
 import json
 from repro.analysis import count_collectives, expected_collectives, \\
     lint_traced_step
-from repro.compat import make_mesh
+import jax
+from jax.sharding import AxisType
 from repro.snn import (DistributedSNN, LIFParams, build_ragged_plan,
                        expand_synapses_sparse, generate_brain_model)
 
@@ -415,7 +416,7 @@ for mesh_spec, tag in [
     (((32,), ("data",)), "1d"),
     (((8, 4), ("pod", "data")), "8x4"),
 ]:
-    mesh = make_mesh(*mesh_spec)
+    mesh = jax.make_mesh(*mesh_spec, axis_types=(AxisType.Auto,) * len(mesh_spec[1]))
     for exch in ("sparse", "ragged"):
         eng = DistributedSNN(mesh=mesh, params=params, exchange=exch,
                              i_ext=4.0, syn=syn)

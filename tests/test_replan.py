@@ -398,14 +398,14 @@ import numpy as np, jax
 import repro.snn.distributed as dist_mod
 from repro.snn import DistributedSNN, LIFParams, BlockSynapses, PlanBuffer
 from repro.snn.ragged import build_ragged_plan
-from repro.compat import make_mesh
+from jax.sharding import AxisType
 from tests.test_snn_sparse import _clustered_w
 
 params = LIFParams(noise_sigma=0.0)
 for n_blocks, mesh_spec in [(8, ((8,), ("data",))), (32, ((8, 4), ("pod", "data")))]:
     w = _clustered_w(64, n_blocks)
     syn = BlockSynapses.from_dense(w, n_blocks)
-    mesh = make_mesh(*mesh_spec)
+    mesh = jax.make_mesh(*mesh_spec, axis_types=(AxisType.Auto,) * len(mesh_spec[1]))
     eng = DistributedSNN(mesh=mesh, params=params, exchange="ragged",
                          i_ext=4.0, syn=syn)
     buf = PlanBuffer(eng)

@@ -62,8 +62,8 @@ def test_collective_parse_and_pod_classification():
 import jax, jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.roofline.hlo import analyze
-from repro.compat import make_mesh
-mesh = make_mesh((2, 4), ("pod", "data"))
+from jax.sharding import AxisType
+mesh = jax.make_mesh((2, 4), ("pod", "data"), axis_types=(AxisType.Auto,) * 2)
 w = jnp.ones((512, 512), jnp.float32)
 ws = jax.device_put(w, NamedSharding(mesh, P("data", None)))
 x = jax.device_put(jnp.ones((16, 512), jnp.float32), NamedSharding(mesh, P(("pod", "data"), None)))

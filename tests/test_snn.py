@@ -128,8 +128,8 @@ w = (rng.random((m, m)) < 0.2).astype(np.float32) * rng.gamma(2., 2., (m, m)).as
 np.fill_diagonal(w, 0)
 params = LIFParams(noise_sigma=0.0)
 ref = SNNEngine(w_syn=jnp.asarray(w), params=params, i_ext=4.0).run(60, key=jax.random.PRNGKey(7))
-from repro.compat import make_mesh
-mesh = make_mesh((2, 4), ("pod", "data"))
+from jax.sharding import AxisType
+mesh = jax.make_mesh((2, 4), ("pod", "data"), axis_types=(AxisType.Auto,) * 2)
 assign = np.repeat(np.arange(8), m // 8)
 perm = partition_permutation(assign, 8)
 wp = w[np.ix_(perm, perm)]
@@ -163,7 +163,7 @@ from repro.snn import (SNNEngine, DistributedSNN, LIFParams, exchange_schedule,
 from repro.snn.distributed import group_mesh_permutation
 from repro.core import TrafficMatrix, needed_sources, pool_block_mask, two_level_routing
 from repro.core.hierarchical import dispatch_messages_from_table
-from repro.compat import make_mesh
+from jax.sharding import AxisType
 
 # 8 devices in 4 communities of 2 (shuffled ids), ring between communities
 grp = np.array([0, 2, 1, 3, 0, 1, 3, 2])
@@ -204,7 +204,7 @@ params = LIFParams(noise_sigma=0.0)
 ref = SNNEngine(w_syn=jnp.asarray(w), params=params, i_ext=4.0).run(
     60, key=jax.random.PRNGKey(7))
 ref_p = np.asarray(ref.spikes)[:, neuron_perm]
-mesh = make_mesh((G, R), ("pod", "data"))
+mesh = jax.make_mesh((G, R), ("pod", "data"), axis_types=(AxisType.Auto,) * 2)
 rasters = {}
 bridge_inner = bridge_inner_from_table(tb)
 for exch in ("flat", "two_level", "sparse", "ragged"):

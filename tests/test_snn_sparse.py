@@ -385,7 +385,7 @@ class TestSparseExchange:
         code = """
 import numpy as np, jax, jax.numpy as jnp
 from repro.snn import SNNEngine, DistributedSNN, LIFParams, BlockSynapses
-from repro.compat import make_mesh
+from jax.sharding import AxisType
 from tests.test_snn_sparse import _clustered_w
 
 m = 64
@@ -396,8 +396,8 @@ ref = SNNEngine(w_syn=jnp.asarray(w), params=params, i_ext=4.0).run(
 ref_r = np.asarray(ref.spikes)
 syn = BlockSynapses.from_dense(w, 8)
 for mesh, tag in [
-    (make_mesh((8,), ("data",)), "1d"),
-    (make_mesh((4, 2), ("pod", "data")), "2d"),
+    (jax.make_mesh((8,), ("data",), axis_types=(AxisType.Auto,)), "1d"),
+    (jax.make_mesh((4, 2), ("pod", "data"), axis_types=(AxisType.Auto,) * 2), "2d"),
 ]:
     for exch in ("sparse", "ragged"):
         d = DistributedSNN(mesh=mesh, params=params, exchange=exch,
@@ -424,13 +424,13 @@ print("OK")
 import numpy as np, jax, jax.numpy as jnp
 from repro.snn import DistributedSNN, LIFParams, BlockSynapses
 from repro.kernels import KernelPolicy
-from repro.compat import make_mesh
+from jax.sharding import AxisType
 from tests.test_snn_sparse import _clustered_w
 
 w = _clustered_w(64, 8)
 params = LIFParams(noise_sigma=0.0)
 syn = BlockSynapses.from_dense(w, 8)
-mesh = make_mesh((4, 2), ("pod", "data"))
+mesh = jax.make_mesh((4, 2), ("pod", "data"), axis_types=(AxisType.Auto,) * 2)
 for exch in ("sparse", "ragged"):
     rasters = {}
     for name, pol in [
@@ -455,14 +455,14 @@ print("OK")
         code = """
 import numpy as np, jax
 from repro.snn import DistributedSNN, LIFParams, BlockSynapses
-from repro.compat import make_mesh
+from jax.sharding import AxisType
 from tests.test_snn_sparse import _clustered_w
 
 params = LIFParams(noise_sigma=0.0)
 for n_blocks, mesh_spec in [(8, ((8,), ("data",))), (32, ((8, 4), ("pod", "data")))]:
     w = _clustered_w(64, n_blocks)
     syn = BlockSynapses.from_dense(w, n_blocks)
-    mesh = make_mesh(*mesh_spec)
+    mesh = jax.make_mesh(*mesh_spec, axis_types=(AxisType.Auto,) * len(mesh_spec[1]))
     rasters = {}
     for mode in ("fused", "per_round"):
         d = DistributedSNN(mesh=mesh, params=params, exchange="ragged",
@@ -480,7 +480,7 @@ print("OK")
 import numpy as np, jax, jax.numpy as jnp
 from repro.snn import (SNNEngine, DistributedSNN, LIFParams,
                        expand_synapses_sparse, generate_brain_model)
-from repro.compat import make_mesh
+from jax.sharding import AxisType
 
 bm = generate_brain_model(n_populations=32, n_regions=8,
                           total_neurons=10**6, seed=1)
@@ -490,7 +490,7 @@ params = LIFParams(noise_sigma=0.0)
 w = jnp.asarray(syn.to_dense())
 ref = SNNEngine(w_syn=w, params=params, i_ext=4.0).run(
     50, key=jax.random.PRNGKey(3))
-mesh = make_mesh((4, 2), ("pod", "data"))
+mesh = jax.make_mesh((4, 2), ("pod", "data"), axis_types=(AxisType.Auto,) * 2)
 d = DistributedSNN(mesh=mesh, params=params, exchange="sparse", i_ext=4.0,
                    syn=syn)
 np.testing.assert_allclose(
@@ -501,10 +501,12 @@ print("OK")
         assert "OK" in run_devices(code)
 
     def test_validation(self):
-        from repro.compat import make_mesh
+        import jax
+        from jax.sharding import AxisType
+
         from repro.snn import DistributedSNN
 
-        mesh = make_mesh((1,), ("data",))
+        mesh = jax.make_mesh((1,), ("data",), axis_types=(AxisType.Auto,))
         with pytest.raises(ValueError, match="w_syn or syn"):
             DistributedSNN(mesh=mesh, params=LIFParams())
         with pytest.raises(ValueError, match="bogus"):
@@ -516,13 +518,15 @@ print("OK")
             )
 
     def test_dense_w_needed_for_flat(self):
-        from repro.compat import make_mesh
+        import jax
+        from jax.sharding import AxisType
+
         from repro.snn import DistributedSNN
 
         syn = BlockSynapses.from_dense(np.zeros((4, 4), np.float32), 1)
         with pytest.raises(ValueError, match="dense w_syn"):
             DistributedSNN(
-                mesh=make_mesh((1,), ("data",)),
+                mesh=jax.make_mesh((1,), ("data",), axis_types=(AxisType.Auto,)),
                 params=LIFParams(),
                 exchange="flat",
                 syn=syn,
