@@ -103,6 +103,7 @@ def spike_accum(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="spike_accum",
     )(s2, w)
     return out[0]
 
@@ -218,5 +219,6 @@ def spike_accum_blocks(
             dimension_semantics=("parallel", "arbitrary", "arbitrary"),
         ),
         interpret=interpret,
+        name="spike_accum_blocks",
     )(src_ids.astype(jnp.int32), s_blocks.reshape(n_blocks, 1, b), blocks)
     return out[0]

@@ -7,6 +7,10 @@ the synaptic accumulation runs the Pallas ``spike_accum_blocks`` kernel.
     PYTHONPATH=src python -m repro.launch.run_brainsim \\
         --populations 512 --neurons-per-pop 32 --steps 1000
 
+``--trace DIR`` runs the whole launch under ``jax.profiler.trace(DIR)``
+with the tracer (:mod:`repro.obs`) on: its planner and executor spans
+and the device's ops land in one profile, on one clock.
+
 On the CPU, with fake host devices for the mesh:
 
     XLA_FLAGS=--xla_force_host_platform_device_count=8 JAX_PLATFORMS=cpu \\
@@ -154,31 +158,38 @@ def main(argv: list[str] | None = None) -> Launch:
     )
     ap.add_argument("--noise", type=float, default=0.0)
     ap.add_argument("--seed", type=int, default=0)
-    ap.add_argument("--trace", metavar="PATH",
-                    help="export a Chrome-trace JSON of the whole run "
-                         "(planner spans + executor profile)")
+    ap.add_argument("--trace", metavar="DIR",
+                    help="profile the whole run into DIR (jax.profiler: "
+                         "the planner and executor spans beside the device's ops)")
     args = ap.parse_args(argv)
+    if not args.trace:
+        return _launch(args)
+    obs.enable()
+    try:
+        with jax.profiler.trace(args.trace, create_perfetto_trace=True):
+            launch = _launch(args)
+    finally:
+        obs.disable()
+    print(f"trace written under {args.trace}")
+    return launch
 
+
+def _launch(args: argparse.Namespace) -> Launch:
     cache = use_compile_cache()
     dev = device_info()
     print(
         f"platform={dev['platform']} device_kind={dev['kind']} "
         f"devices={dev['count']} compile_cache={cache}"
     )
-    if args.trace:
-        obs.enable()
-    eng = build_engine(
-        args.populations,
-        args.neurons_per_pop,
-        seed=args.seed,
-        exchange=args.exchange,
-        noise=args.noise,
-    )
+    with obs.span("launch.build", cat="plan", tid="launch"):
+        eng = build_engine(
+            args.populations,
+            args.neurons_per_pop,
+            seed=args.seed,
+            exchange=args.exchange,
+            noise=args.noise,
+        )
     key = jax.random.PRNGKey(args.seed)
-    if args.trace:
-        prof = eng.step_profile(min(args.steps, 4), key=key)
-        print("step profile: " + "  ".join(
-            f"{k}={v:.4g}" for k, v in sorted(prof.items())))
     with obs.span("launch.run", cat="exec", tid="launch",
                   args={"exchange": eng.exchange, "steps": args.steps}):
         raster, compiled, compile_s, steps_per_s = run_timed(eng, args.steps, key=key)
@@ -193,10 +204,6 @@ def main(argv: list[str] | None = None) -> Launch:
     )
     vol = eng.exchange_stats()
     print("slow-axis bytes/step: " + "  ".join(f"{k}={v}" for k, v in sorted(vol.items())))
-    if args.trace:
-        obs.disable()
-        obs.write_chrome_trace(args.trace)
-        print(f"trace written to {args.trace}")
     return Launch(
         engine=eng,
         raster=raster,

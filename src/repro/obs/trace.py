@@ -17,6 +17,11 @@ human-readable lanes.
 
 Timestamps are microseconds on one shared clock: wall time
 (``time.perf_counter``) relative to the moment the tracer was enabled.
+While the tracer is enabled, a span also opens a
+``jax.profiler.TraceAnnotation`` of its name when ``jax`` is already
+imported (the tracer itself imports nothing), so under
+``jax.profiler.trace`` the program's host spans land in the profile on
+the device trace's clock, beside the ops they dispatched.
 Simulated-time producers (:mod:`repro.obs.timeline`) anchor sim second
 0 at the wall-clock moment the simulation ran — one time axis for
 planner spans, supervisor events, and simulated transmissions.  Tests
@@ -29,6 +34,7 @@ hits, recovery retries); ``metrics_snapshot()`` merges into the
 """
 from __future__ import annotations
 
+import sys
 import time
 
 __all__ = [
@@ -74,7 +80,7 @@ _NOOP = _NoopSpan()
 class _Span:
     """A live span: records one ``X`` (complete) event on exit."""
 
-    __slots__ = ("_tracer", "name", "cat", "pid", "tid", "args", "_ts")
+    __slots__ = ("_tracer", "name", "cat", "pid", "tid", "args", "_ts", "_ann")
 
     def __init__(self, tracer, name, cat, pid, tid, args):
         self._tracer = tracer
@@ -84,16 +90,23 @@ class _Span:
         self.tid = tid
         self.args = dict(args) if args else {}
         self._ts = 0.0
+        self._ann = None
 
     def set(self, **args) -> None:
         """Attach result arguments discovered while the span is open."""
         self.args.update(args)
 
     def __enter__(self):
+        jax = sys.modules.get("jax")
+        if jax is not None:  # the same span on the profiler's clock
+            self._ann = jax.profiler.TraceAnnotation(self.name)
+            self._ann.__enter__()
         self._ts = self._tracer.now_us()
         return self
 
     def __exit__(self, *exc):
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
         tr = self._tracer
         ev = {
             "ph": "X",

@@ -69,9 +69,27 @@ from repro.snn.neuron import (
 __all__ = [
     "DistributedSNN",
     "PlanBuffer",
+    "STEP_SCOPES",
     "partition_permutation",
     "group_mesh_permutation",
 ]
+
+#: the ``jax.named_scope`` names of the compiled sparse/ragged step
+#: (:func:`_sparse_step`), one per part of the work: the fast-axis
+#: gather (level 1); the slow-axis exchange (level 2) as packing the
+#: payload, moving it and landing it in the block buffer; the synaptic
+#: accumulation; and the noise draw with the neuron update.  They reach
+#: the compiled program's ``op_name`` metadata, so a device trace can
+#: name each op's part of the step.
+STEP_SCOPES = (
+    "exchange/level1",
+    "exchange/level2/pack",
+    "exchange/level2/send",
+    "exchange/level2/unpack",
+    "accumulate",
+    "neuron",
+)
+LEVEL1, PACK, SEND, UNPACK, ACCUMULATE, NEURON = STEP_SCOPES
 
 
 def group_mesh_permutation(tb) -> tuple[np.ndarray, tuple[int, int]]:
@@ -160,30 +178,31 @@ class DistributedSNN:
     plan: RaggedPlan | None = None
 
     def __post_init__(self):
-        if self.params is None:
-            raise ValueError("params is required")
-        if self.exchange not in ("flat", "two_level", "sparse", "ragged"):
-            raise ValueError(self.exchange)
-        if self.ragged_scatter not in ("fused", "per_round"):
-            raise ValueError(self.ragged_scatter)
-        if self.exchange == "two_level" and len(self.mesh.axis_names) < 2:
-            raise ValueError("two_level exchange needs a 2-D mesh")
-        if self.w_syn is None and self.syn is None:
-            raise ValueError("need w_syn or syn")
-        if self.w_syn is None and self.exchange not in ("sparse", "ragged"):
-            raise ValueError(f"exchange={self.exchange!r} needs dense w_syn")
-        if self.syn is not None and self.syn.n_blocks != self.n_devices:
-            raise ValueError(
-                f"syn has {self.syn.n_blocks} blocks for {self.n_devices} devices"
-            )
-        if self.plan is not None:
-            if self.exchange != "ragged":
-                raise ValueError("plan= only applies to exchange='ragged'")
-            if self.plan.mesh_shape != self._mesh_groups():
+        with obs.span("snn.engine_init", cat="build", tid="snn"):
+            if self.params is None:
+                raise ValueError("params is required")
+            if self.exchange not in ("flat", "two_level", "sparse", "ragged"):
+                raise ValueError(self.exchange)
+            if self.ragged_scatter not in ("fused", "per_round"):
+                raise ValueError(self.ragged_scatter)
+            if self.exchange == "two_level" and len(self.mesh.axis_names) < 2:
+                raise ValueError("two_level exchange needs a 2-D mesh")
+            if self.w_syn is None and self.syn is None:
+                raise ValueError("need w_syn or syn")
+            if self.w_syn is None and self.exchange not in ("sparse", "ragged"):
+                raise ValueError(f"exchange={self.exchange!r} needs dense w_syn")
+            if self.syn is not None and self.syn.n_blocks != self.n_devices:
                 raise ValueError(
-                    f"plan mesh {self.plan.mesh_shape} != engine mesh "
-                    f"{self._mesh_groups()}"
+                    f"syn has {self.syn.n_blocks} blocks for {self.n_devices} devices"
                 )
+            if self.plan is not None:
+                if self.exchange != "ragged":
+                    raise ValueError("plan= only applies to exchange='ragged'")
+                if self.plan.mesh_shape != self._mesh_groups():
+                    raise ValueError(
+                        f"plan mesh {self.plan.mesh_shape} != engine mesh "
+                        f"{self._mesh_groups()}"
+                    )
 
     @property
     def axis_names(self) -> tuple[str, ...]:
@@ -341,56 +360,6 @@ class DistributedSNN:
         w = jax.device_put(self.w_syn, NamedSharding(self.mesh, col_spec))
         return jax.jit(_run)(v0, u0, keys, w)
 
-    def step_profile(
-        self, n_steps: int = 2, *, key: jax.Array | None = None
-    ) -> dict[str, float]:
-        """Opt-in blocked per-phase host profile of one sparse/ragged run.
-
-        Phases are timed on the host with ``jax.block_until_ready`` at
-        each boundary — *blocked* timings, so a phase's number is wall
-        time until its results exist, not dispatch time:
-
-        * ``prepare_s`` — :meth:`compile` as a whole: building/looking up
-          the step, staging its device inputs, lowering and compiling;
-        * ``compile_s`` — the lowering and compiling within it;
-        * ``first_call_s`` — first execution;
-        * ``steady_call_s`` — second execution;
-
-        plus the engine's :meth:`exchange_stats` byte ledger
-        (``bytes_per_step``, chosen exchange) and the process-wide
-        ``_StepKey`` compile-cache hit/miss counters.  Each phase is
-        also emitted as a tracer span and the bytes as counters, so a
-        ``--trace`` run shows the executor on the shared clock.
-        """
-        if self.exchange not in ("sparse", "ragged"):
-            raise ValueError("step_profile covers exchange='sparse'/'ragged'")
-        prof: dict[str, float] = {}
-        with obs.span("snn.step_profile", cat="exec", tid="snn",
-                      args={"exchange": self.exchange, "n_steps": n_steps}):
-            t = time.perf_counter()
-            with obs.span("snn.prepare", cat="exec", tid="snn"):
-                compiled, args, prof["compile_s"] = self.compile(n_steps, key=key)
-                jax.block_until_ready(args)
-            prof["prepare_s"] = time.perf_counter() - t
-            t = time.perf_counter()
-            with obs.span("snn.first_call", cat="exec", tid="snn"):
-                jax.block_until_ready(compiled(*args))
-            prof["first_call_s"] = time.perf_counter() - t
-            t = time.perf_counter()
-            with obs.span("snn.steady_call", cat="exec", tid="snn"):
-                jax.block_until_ready(compiled(*args))
-            prof["steady_call_s"] = time.perf_counter() - t
-        stats = self.exchange_stats()
-        bytes_step = float(stats[self.exchange])
-        prof["bytes_per_step"] = bytes_step
-        obs.counter("snn.exchange_bytes",
-                    {k: float(v) for k, v in stats.items()}, tid="snn")
-        obs.metric_gauge("snn.bytes_per_step", bytes_step)
-        ci = _sparse_step.cache_info()
-        prof["step_cache_hits"] = float(ci.hits)
-        prof["step_cache_misses"] = float(ci.misses)
-        return prof
-
     def compile(
         self, n_steps: int, *, key: jax.Array | None = None
     ) -> tuple[jax.stages.Compiled, tuple, float]:
@@ -400,15 +369,27 @@ class DistributedSNN:
         Returns ``(compiled, args, compile_s)``: ``compiled(*args)`` runs
         the ``n_steps`` simulation and returns the raster ``[T, M]``, as
         :meth:`run` does; ``compile_s`` is the wall time of lowering and
-        compiling alone (input staging excluded).
+        compiling alone (input staging excluded).  While the tracer
+        (:mod:`repro.obs`) is on, staging, lowering and compiling are the
+        spans ``snn.stage``, ``snn.lower`` and ``snn.compile``, and the
+        counter ``snn.exchange_bytes`` records the slow-axis bytes a step
+        moves (``{"level2": exchange_stats()[exchange]}``).
         """
         if self.exchange not in ("sparse", "ragged"):
             raise ValueError("compile covers exchange='sparse'/'ragged'")
         key = jax.random.PRNGKey(0) if key is None else key
         fn, args = self._sparse_callable_and_args(n_steps, key=key)
         t = time.perf_counter()
-        compiled = fn.lower(*args).compile()
-        return compiled, args, time.perf_counter() - t
+        with obs.span("snn.lower", cat="exec", tid="snn"):
+            lowered = fn.lower(*args)
+        with obs.span("snn.compile", cat="exec", tid="snn"):
+            compiled = lowered.compile()
+        compile_s = time.perf_counter() - t
+        if obs.is_enabled():
+            # slow-axis bytes a step of the plan just compiled
+            obs.counter("snn.exchange_bytes",
+                        {"level2": self.exchange_stats()[self.exchange]}, tid="snn")
+        return compiled, args, compile_s
 
     def _step_key(self, n_steps: int) -> "_StepKey":
         return _StepKey(
@@ -437,40 +418,44 @@ class DistributedSNN:
         """
         syn = self._block_synapses()
         n_dev = self.n_devices
-        src_pad, blk_pad = syn.padded()  # [n_dev, K], [n_dev, K, B, B]
-        if self.exchange == "ragged":
-            plan = self._ragged_plan()
-            # per-device (send, recv) index rows, one [n_dev, 2, K_r]
-            # array per live round (round widths differ — static shapes
-            # per ppermute, not across them)
-            idx_arrays = tuple(
-                jnp.asarray(np.stack([rnd.send_idx, rnd.recv_idx], axis=1))
-                for rnd in plan.rounds
-                if rnd.pairs
-            )
-        else:
-            idx_arrays = ()
         misses_before = _sparse_step.cache_info().misses
         fn = _sparse_step(self._step_key(n_steps))
         if _sparse_step.cache_info().misses > misses_before:
             obs.metric_inc("snn.step_cache_misses")
         else:
             obs.metric_inc("snn.step_cache_hits")
-        # one key per device over the full mesh (see the dense path)
-        keys = jax.random.split(key, n_dev)
-        st0 = init_state(syn.n_neurons, self.params, key)
-        vec_spec = P(self.axis_names)
-        sharding = NamedSharding(self.mesh, vec_spec)
-        v0 = jax.device_put(st0.v, sharding)
-        u0 = jax.device_put(st0.u, sharding)
-        keys = jax.device_put(keys, sharding)
-        blk_sharding = NamedSharding(self.mesh, vec_spec)
-        # straight from the host to each device's shard (no staging of the
-        # whole tile array on the first device)
-        src_arr = jax.device_put(src_pad.astype(np.int32), blk_sharding)
-        blk_arr = jax.device_put(blk_pad, blk_sharding)
-        idx_put = tuple(jax.device_put(a, blk_sharding) for a in idx_arrays)
-        return fn, (v0, u0, keys, src_arr, blk_arr, idx_put)
+        with obs.span("snn.stage", cat="exec", tid="snn"):
+            src_pad, blk_pad = syn.padded()  # [n_dev, K], [n_dev, K, B, B]
+            if self.exchange == "ragged":
+                plan = self._ragged_plan()
+                # per-device (send, recv) index rows, one [n_dev, 2, K_r]
+                # array per live round (round widths differ — static shapes
+                # per ppermute, not across them)
+                idx_arrays = tuple(
+                    jnp.asarray(np.stack([rnd.send_idx, rnd.recv_idx], axis=1))
+                    for rnd in plan.rounds
+                    if rnd.pairs
+                )
+            else:
+                idx_arrays = ()
+            # one key per device over the full mesh (see the dense path)
+            keys = jax.random.split(key, n_dev)
+            st0 = init_state(syn.n_neurons, self.params, key)
+            vec_spec = P(self.axis_names)
+            sharding = NamedSharding(self.mesh, vec_spec)
+            v0 = jax.device_put(st0.v, sharding)
+            u0 = jax.device_put(st0.u, sharding)
+            keys = jax.device_put(keys, sharding)
+            blk_sharding = NamedSharding(self.mesh, vec_spec)
+            # straight from the host to each device's shard (no staging of
+            # the whole tile array on the first device)
+            src_arr = jax.device_put(src_pad.astype(np.int32), blk_sharding)
+            blk_arr = jax.device_put(blk_pad, blk_sharding)
+            idx_put = tuple(jax.device_put(a, blk_sharding) for a in idx_arrays)
+            args = (v0, u0, keys, src_arr, blk_arr, idx_put)
+            if obs.is_enabled():  # the span ends when the arrays are resident
+                jax.block_until_ready(args)
+        return fn, args
 
     def _run_sparse(self, n_steps: int, *, key: jax.Array) -> jax.Array:
         fn, args = self._sparse_callable_and_args(n_steps, key=key)
@@ -531,6 +516,10 @@ def _sparse_step(key: _StepKey):
     :func:`repro.kernels.spike_currents_blocks` so ``policy`` flips
     einsum ↔ Pallas without touching the exchange.
 
+    Each part of a step runs under its name in :data:`STEP_SCOPES`
+    (``jax.named_scope``: metadata only, the compiled program is the
+    same), so the ops of a device trace can be told apart by part.
+
     The ``lru_cache`` is what makes the double-buffered plan swap
     stall-free: engines whose plans share a signature get the *same*
     jitted callable, and the per-round index rows / synapse tiles are
@@ -554,7 +543,8 @@ def _sparse_step(key: _StepKey):
 
     def gather_group(spikes_loc):
         if r > 1:
-            return jax.lax.all_gather(spikes_loc, inner, axis=0, tiled=True)
+            with jax.named_scope(LEVEL1):
+                return jax.lax.all_gather(spikes_loc, inner, axis=0, tiled=True)
         return spikes_loc  # [R·B] group spike block
 
     def gather_blocks(spikes_loc):
@@ -562,18 +552,22 @@ def _sparse_step(key: _StepKey):
         the schedule skipped the transfer)."""
         s_grp = gather_group(spikes_loc)
         rb = s_grp.shape[0]
-        gid = jax.lax.axis_index(slow)
-        buf = jnp.zeros((g, rb), jnp.float32)
-        buf = buf.at[gid].set(s_grp)
+        with jax.named_scope(PACK):
+            gid = jax.lax.axis_index(slow)
+            buf = jnp.zeros((g, rb), jnp.float32)
+            buf = buf.at[gid].set(s_grp)
         for shift, pairs in enumerate(schedule, start=1):
             if not pairs:
                 continue
-            recv = jax.lax.ppermute(s_grp, slow, perm=pairs)
+            with jax.named_scope(SEND):
+                recv = jax.lax.ppermute(s_grp, slow, perm=pairs)
             # whatever arrived in the shift-`shift` round came from
             # group (gid - shift); untargeted receivers got zeros and
             # write zeros into an otherwise-untouched slot
-            buf = buf.at[(gid - shift) % g].set(recv)
-        return buf.reshape(n_dev, rb // r)
+            with jax.named_scope(UNPACK):
+                buf = buf.at[(gid - shift) % g].set(recv)
+        with jax.named_scope(UNPACK):
+            return buf.reshape(n_dev, rb // r)
 
     def gather_blocks_ragged(spikes_loc, idx_loc):
         """Ragged level-2: bridge-only packed ppermute + fast-axis
@@ -591,34 +585,39 @@ def _sparse_step(key: _StepKey):
         s_grp = gather_group(spikes_loc)
         rb = s_grp.shape[0]
         gid = jax.lax.axis_index(slow)
-        parts = [s_grp]  # local block → own row, columns [0, rb)
-        flat_idx = [gid * (rb + 1) + jnp.arange(rb, dtype=jnp.int32)]
-        buf = None
-        if not fused:
-            buf = jnp.zeros((g, rb + 1), jnp.float32)
-            buf = buf.at[gid, :rb].set(s_grp)
+        with jax.named_scope(UNPACK):
+            parts = [s_grp]  # local block → own row, columns [0, rb)
+            flat_idx = [gid * (rb + 1) + jnp.arange(rb, dtype=jnp.int32)]
+            buf = None
+            if not fused:
+                buf = jnp.zeros((g, rb + 1), jnp.float32)
+                buf = buf.at[gid, :rb].set(s_grp)
         for (shift, _width, perm), idx in zip(schedule, idx_loc):
-            send_idx = idx[0, 0]  # [K_r] columns of s_grp to pack
-            recv_idx = idx[0, 1]  # [K_r] slots (rb = trash)
-            payload = s_grp[send_idx]
-            recv = jax.lax.ppermute(payload, axes, perm=perm)
-            if r > 1:
-                # only the receiving bridge got data; everyone else
-                # holds zeros, so a psum is the intra-group broadcast
-                recv = jax.lax.psum(recv, inner)
-            row = (gid - shift) % g
+            with jax.named_scope(PACK):
+                send_idx = idx[0, 0]  # [K_r] columns of s_grp to pack
+                payload = s_grp[send_idx]
+            with jax.named_scope(SEND):
+                recv = jax.lax.ppermute(payload, axes, perm=perm)
+                if r > 1:
+                    # only the receiving bridge got data; everyone else
+                    # holds zeros, so a psum is the intra-group broadcast
+                    recv = jax.lax.psum(recv, inner)
+            with jax.named_scope(UNPACK):
+                recv_idx = idx[0, 1]  # [K_r] slots (rb = trash)
+                row = (gid - shift) % g
+                if fused:
+                    parts.append(recv)
+                    flat_idx.append(row * (rb + 1) + recv_idx)
+                else:
+                    buf = buf.at[row, recv_idx].add(recv)
+        with jax.named_scope(UNPACK):
             if fused:
-                parts.append(recv)
-                flat_idx.append(row * (rb + 1) + recv_idx)
-            else:
-                buf = buf.at[row, recv_idx].add(recv)
-        if fused:
-            buf = jax.ops.segment_sum(
-                jnp.concatenate(parts),
-                jnp.concatenate(flat_idx),
-                num_segments=g * (rb + 1),
-            ).reshape(g, rb + 1)
-        return buf[:, :rb].reshape(n_dev, rb // r)
+                buf = jax.ops.segment_sum(
+                    jnp.concatenate(parts),
+                    jnp.concatenate(flat_idx),
+                    num_segments=g * (rb + 1),
+                ).reshape(g, rb + 1)
+            return buf[:, :rb].reshape(n_dev, rb // r)
 
     @functools.partial(
         shard_map,
@@ -639,13 +638,15 @@ def _sparse_step(key: _StepKey):
                 s_blocks = gather_blocks_ragged(prev_loc, idx_loc)
             else:
                 s_blocks = gather_blocks(prev_loc)
-            i_syn = (
-                spike_currents_blocks(
-                    s_blocks, src_ids_loc, blocks_loc, policy=policy
+            with jax.named_scope(ACCUMULATE):
+                i_syn = (
+                    spike_currents_blocks(
+                        s_blocks, src_ids_loc, blocks_loc, policy=policy
+                    )
+                    + i_ext
                 )
-                + i_ext
-            )
-            state, spikes = step(state, i_syn, params)
+            with jax.named_scope(NEURON):
+                state, spikes = step(state, i_syn, params)
             return (state, spikes), spikes
 
         (_, _), raster = jax.lax.scan(

@@ -21,6 +21,8 @@ import dataclasses
 
 import numpy as np
 
+from repro.obs import trace as obs
+
 __all__ = [
     "BlockSynapses",
     "exchange_schedule",
@@ -145,23 +147,24 @@ class BlockSynapses:
     ) -> "BlockSynapses":
         """Build from COO tiles ``(src[k], dst[k], tiles[k, B, B])``;
         duplicates are rejected, all-zero tiles are dropped."""
-        src = np.asarray(src, dtype=np.int64)
-        dst = np.asarray(dst, dtype=np.int64)
-        tiles = np.asarray(tiles, dtype=np.float32)
-        if tiles.shape[0]:
-            keep = np.abs(tiles).sum(axis=(1, 2)) > 0
-            src, dst, tiles = src[keep], dst[keep], tiles[keep]
-        key = dst * n_blocks + src
-        if np.unique(key).size != key.size:
-            raise ValueError("duplicate (src, dst) tiles")
-        order = np.argsort(key, kind="stable")
-        src, tiles = src[order], tiles[order]
-        counts = np.bincount(dst, minlength=n_blocks)
-        indptr = np.zeros(n_blocks + 1, dtype=np.int64)
-        np.cumsum(counts, out=indptr[1:])
-        syn = cls(indptr=indptr, src_ids=src, blocks=tiles, n_blocks=n_blocks)
-        syn.validate()
-        return syn
+        with obs.span("snn.from_tiles", cat="build", tid="snn"):
+            src = np.asarray(src, dtype=np.int64)
+            dst = np.asarray(dst, dtype=np.int64)
+            tiles = np.asarray(tiles, dtype=np.float32)
+            if tiles.shape[0]:
+                keep = np.abs(tiles).sum(axis=(1, 2)) > 0
+                src, dst, tiles = src[keep], dst[keep], tiles[keep]
+            key = dst * n_blocks + src
+            if np.unique(key).size != key.size:
+                raise ValueError("duplicate (src, dst) tiles")
+            order = np.argsort(key, kind="stable")
+            src, tiles = src[order], tiles[order]
+            counts = np.bincount(dst, minlength=n_blocks)
+            indptr = np.zeros(n_blocks + 1, dtype=np.int64)
+            np.cumsum(counts, out=indptr[1:])
+            syn = cls(indptr=indptr, src_ids=src, blocks=tiles, n_blocks=n_blocks)
+            syn.validate()
+            return syn
 
     @classmethod
     def from_dense(cls, w: np.ndarray, n_blocks: int) -> "BlockSynapses":

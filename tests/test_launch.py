@@ -83,21 +83,29 @@ print("OK")
 
 def test_compile_runs_what_run_runs():
     """``DistributedSNN.compile`` stages and compiles the step ``run``
-    executes, and ``step_profile`` times its phases through it."""
+    executes, and with the tracer on records the slow-axis bytes a step
+    of the compiled plan moves."""
     import jax
     import numpy as np
 
+    from repro import obs
     from repro.launch import run_brainsim
 
     eng = run_brainsim.build_engine(32, 4, noise=1.0)
     key = jax.random.PRNGKey(3)
-    compiled, args, compile_s = eng.compile(50, key=key)
+    obs.enable()
+    try:
+        compiled, args, compile_s = eng.compile(50, key=key)
+        counters = [e for e in obs.events() if e["ph"] == "C"]
+    finally:
+        obs.disable()
+        obs.clear()
     assert compile_s > 0
     np.testing.assert_array_equal(
         np.asarray(compiled(*args)), np.asarray(eng.run(50, key=key))
     )
-    prof = eng.step_profile(4, key=key)
-    assert 0 < prof["compile_s"] <= prof["prepare_s"]
+    assert [c["name"] for c in counters] == ["snn.exchange_bytes"]
+    assert counters[0]["args"] == {"level2": float(eng.exchange_stats()[eng.exchange])}
 
 
 def test_chip_smoke_fails_without_tpu():

@@ -93,3 +93,47 @@ def test_sparse_step_compiles_with_kernel(topo):
         (),
     ).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_ragged_step_for_v5e_carries_the_scopes(topo):
+    """The 2×2 ragged step as the chip compiles it: every named scope
+    reaches the ``op_name`` metadata, the Pallas kernel keeps its name
+    under ``accumulate``, and the collective-permute and all-reduce sit
+    under ``exchange/level2/send``."""
+    import re
+
+    from repro.snn.distributed import STEP_SCOPES
+
+    b, k, width = 128, 4, 181
+    mesh = Mesh(np.asarray(topo.devices[:4]).reshape(2, 2), ("pod", "data"))
+    fn = _sparse_step(
+        _StepKey(
+            mesh=mesh,
+            params=LIFParams(noise_sigma=1.0),
+            policy=KernelPolicy(use_pallas=True),
+            i_ext=1.0,
+            ragged_scatter="fused",
+            n_steps=4,
+            signature=("ragged", ((1, width, ((1, 2), (2, 1))),)),
+        )
+    )
+    sh = NamedSharding(mesh, P(("pod", "data")))
+    key = jax.eval_shape(lambda: jax.random.split(jax.random.PRNGKey(0), 4))
+    text = fn.lower(
+        _sds((4 * b,), jnp.float32, sh),
+        _sds((4 * b,), jnp.float32, sh),
+        _sds(key.shape, key.dtype, sh),
+        _sds((4, k), jnp.int32, sh),
+        _sds((4, k, b, b), jnp.float32, sh),
+        (_sds((4, 2, width), jnp.int32, sh),),
+    ).compile().as_text()
+    ops = re.findall(r'^\s*(?:ROOT )?%?([\w.\-]+) = .*? ([a-z][a-z0-9\-]*)\(.*?op_name="([^"]*)"',
+                     text, re.M)
+    paths = [p + "/" for _, _, p in ops]
+    for scope in STEP_SCOPES:
+        assert any(f"/{scope}/" in p for p in paths), scope
+    kernel = [p for name, kind, p in ops
+              if kind == "custom-call" and name.startswith("spike_accum_blocks")]
+    assert kernel and all("/accumulate/" in p for p in kernel)
+    sends = [p for _, kind, p in ops if kind.startswith(("collective-permute", "all-reduce"))]
+    assert sends and all("/exchange/level2/send/" in p for p in sends)
