@@ -128,7 +128,12 @@ def spike_currents_blocks(
     policy: KernelPolicy = KernelPolicy(),
 ) -> jax.Array:
     """Block-CSR synaptic accumulation (the ``exchange='sparse'`` /
-    ``'ragged'`` layout; the distributed engine's per-step hot-spot)."""
+    ``'ragged'`` layout; the distributed engine's per-step hot-spot).
+
+    Spikes are 0/1 events.  The Pallas kernel is event-driven: it reads a
+    nonzero entry as one spike and fetches only the weight strips of the
+    neurons that fired, so a weighted spike vector would be read as 1s.
+    """
     if policy.use_pallas:
         return _spike_blocks(s_blocks, src_ids, blocks, interpret=policy.interpret)
     return _ref.spike_accum_blocks_ref(s_blocks, src_ids, blocks)

@@ -9,7 +9,10 @@ the synaptic accumulation runs the Pallas ``spike_accum_blocks`` kernel.
 
 ``--trace DIR`` runs the whole launch under ``jax.profiler.trace(DIR)``
 with the tracer (:mod:`repro.obs`) on: its planner and executor spans
-and the device's ops land in one profile, on one clock.
+and the device's ops land in one profile, on one clock.  After the timed
+run the launcher prints how many 8-row weight strips the accumulation
+streamed a step (``DistributedSNN.accum_stats``, counter
+``snn.accum_strips``).
 
 On the CPU, with fake host devices for the mesh:
 
@@ -201,6 +204,12 @@ def _launch(args: argparse.Namespace) -> Launch:
     print(
         f"compile {compile_s:.3f} s, {steps_per_s:.1f} steps/s "
         f"(measured on {dev['count']}× {dev['kind']})"
+    )
+    strips = eng.accum_stats(raster)
+    obs.counter("snn.accum_strips", strips, tid="snn")
+    print(
+        f"accumulation streamed {strips['mean']:.2f} of {strips['of']} weight strips "
+        f"a step ({strips['mean'] / strips['of']:.4%}), at most {strips['max']}"
     )
     vol = eng.exchange_stats()
     print("slow-axis bytes/step: " + "  ".join(f"{k}={v}" for k, v in sorted(vol.items())))

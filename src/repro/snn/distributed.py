@@ -473,6 +473,47 @@ class DistributedSNN:
         fn, args = self._sparse_callable_and_args(n_steps, key=key)
         return jax.make_jaxpr(fn)(*args)
 
+    def accum_stats(self, raster: np.ndarray) -> dict[str, float]:
+        """Strips the event-driven accumulation streams on one device per
+        step of ``raster`` (``[T, M]``, as :meth:`run` returns it), out of
+        the ``K·B/8`` 8-row strips of the device's stored tiles
+        (:func:`repro.kernels.spike_accum.spike_strips`).  Host NumPy, off
+        the timed path.
+
+        Step ``t`` accumulates the spikes of step ``t − 1`` (none at step
+        0) that reach the device: the whole group blocks the schedule
+        moves, or for ``exchange='ragged'`` the device's own group and the
+        columns the plan sends to it.  Returns ``{"mean", "max", "of"}``:
+        the mean and the largest count over steps and devices, and
+        ``K·B/8``.
+        """
+        syn = self._block_synapses()
+        b, n_dev = syn.block_size, self.n_devices
+        g, r = self._mesh_groups()
+        deg = np.diff(syn.indptr)
+        k = max(int(deg.max()) if deg.size else 0, 1)
+        src = np.zeros((n_dev, k), np.int64)  # padded(): padding reads block 0
+        for d in range(n_dev):
+            src[d, : deg[d]] = syn.src_ids[syn.indptr[d] : syn.indptr[d + 1]]
+        group = np.arange(n_dev) // r
+        if self.exchange == "ragged":
+            plan = self._ragged_plan()
+            seen = np.zeros((g, g, r * b), bool)  # [receiving, sending group, column]
+            seen[np.arange(g), np.arange(g)] = True
+            for (gs, gd), cols in plan.pair_cols.items():
+                seen[gd, gs, cols] = True
+        else:
+            moved = pool_block_mask(syn.mask(), group, g)  # [sending, receiving]
+            seen = np.repeat(moved.T[:, :, None], r * b, axis=2)
+        seen = seen.reshape(g, n_dev, b)[group]  # [device, source block, column]
+        fired = np.asarray(raster[:-1]).reshape(-1, n_dev, b) != 0
+        counts = np.zeros((fired.shape[0] + 1, n_dev), np.int64)
+        for d in range(n_dev):
+            spikes = fired[:, src[d]] & seen[d, src[d]]  # [T - 1, K, B]
+            strips = spikes.reshape(spikes.shape[0], -1, 8).any(axis=2)
+            counts[1:, d] = strips.sum(axis=1)
+        return {"mean": float(counts.mean()), "max": int(counts.max()), "of": k * b // 8}
+
 
 @dataclasses.dataclass(frozen=True)
 class _StepKey:

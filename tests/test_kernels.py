@@ -10,7 +10,7 @@ from repro.kernels import ref as R
 from repro.kernels.decode_attention import decode_attention
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.rglru_scan import rglru_scan
-from repro.kernels.spike_accum import spike_accum, spike_accum_blocks
+from repro.kernels.spike_accum import spike_accum, spike_accum_blocks, spike_strips
 from repro.kernels.ssd_scan import ssd_scan
 
 RNG = np.random.default_rng(0)
@@ -130,38 +130,84 @@ def test_spike_accum_weighted_spikes():
 
 
 @pytest.mark.parametrize(
-    "n_blocks,b,bj,k",
+    "n_blocks,b,bj,k,firing",
     [
-        (2, 1024, 1024, 4),  # 2 × 2 sub-tiles of 512, K > n_blocks
-        (3, 1536, 640, 3),  # 3 row tiles of 512; 5 column tiles of 128
-        (4, 1280, 384, 5),  # 5 row tiles of 256; one whole-width column tile
-        (5, 96, 64, 3),  # B below the bound: whole-dimension tiles
+        (2, 1024, 1024, 4, "sparse"),  # K > n_blocks
+        (3, 1536, 640, 3, "sparse"),
+        (4, 1280, 384, 5, "sparse"),
+        (5, 96, 64, 3, "sparse"),  # small B and Bj: whole-dimension tiles
+        (3, 512, 256, 4, "silent"),  # no spike at all: count 0 gives zeros
+        (2, 256, 384, 3, "all"),  # every neuron fires: every strip streamed
+        (3, 64, 24576, 3, "last_row"),  # two column tiles of 12,288
+        (4, 128, 256, 4, "padding_fires"),
     ],
 )
-def test_spike_accum_blocks_vs_float64(n_blocks, b, bj, k):
-    """The re-tiled block-CSR kernel against float64 ``Σ_k s[src_k] @ W_k``,
-    with silent source blocks, silent row tiles inside a firing block,
-    repeated sources and an all-zero padding tile."""
+def test_spike_accum_blocks_vs_float64(n_blocks, b, bj, k, firing):
+    """The event-driven block-CSR kernel against float64
+    ``Σ_k s[src_k] @ W_k``.  ``sparse``: silent source blocks, silent rows
+    inside a firing block, repeated sources and an all-zero padding tile;
+    ``silent``: no spike; ``all``: every neuron fires; ``last_row``: one
+    spike, in the last row of the last tile; ``padding_fires``: only the
+    source of a zero padding tile fires."""
     rng = np.random.default_rng(n_blocks * b + k)
-    s = (rng.random((n_blocks, b)) < 0.05).astype(np.float32)
-    s[1] = 0.0  # a silent source block
-    s[0, : min(b, 256)] = 0.0  # a silent row tile inside a firing block
     src = rng.integers(0, n_blocks, size=k)
-    src[0] = 1  # at least one tile reads the silent block
     w = rng.normal(size=(k, b, bj)).astype(np.float32)
-    w[-1] = 0.0  # zero padding tile (padded() layout)
+    s = np.zeros((n_blocks, b), np.float32)
+    if firing == "sparse":
+        s[:] = rng.random((n_blocks, b)) < 0.05
+        s[1] = 0.0  # a silent source block
+        s[0, : min(b, 256)] = 0.0  # silent rows inside a firing block
+        src[0] = 1  # at least one tile reads the silent block
+        w[-1] = 0.0  # zero padding tile (padded() layout)
+    elif firing == "all":
+        s[:] = 1.0
+    elif firing == "last_row":
+        src[-1] = n_blocks - 1
+        src[:-1] = 0
+        s[n_blocks - 1, b - 1] = 1.0
+    elif firing == "padding_fires":
+        src[:-1] = 1
+        src[-1] = 0
+        w[-1] = 0.0
+        s[0] = rng.random(b) < 0.5
     out = spike_accum_blocks(
         jnp.asarray(s), jnp.asarray(src), jnp.asarray(w), interpret=True
     )
     ref = np.einsum("kb,kbj->j", s.astype(np.float64)[src], w.astype(np.float64))
+    if firing in ("silent", "padding_fires"):
+        np.testing.assert_array_equal(np.asarray(out), np.zeros(bj, np.float32))
     np.testing.assert_allclose(np.asarray(out), ref, rtol=1e-5, atol=1e-4)
 
 
 def test_spike_accum_blocks_rejects_untileable_b():
-    """A B above the tile bound with no multiple-of-128 divisor is refused,
+    """A B that is not a whole number of 8-row strips, and a column width
+    above the tile bound with no multiple-of-128 divisor, are refused,
     never silently padded."""
-    with pytest.raises(ValueError, match="multiple of 128"):
+    with pytest.raises(ValueError, match="multiple of 8"):
         spike_accum_blocks(
-            jnp.zeros((2, 600)), jnp.array([0, 1]), jnp.zeros((2, 600, 128)),
+            jnp.zeros((2, 100)), jnp.array([0, 1]), jnp.zeros((2, 100, 128)),
             interpret=True,
         )
+    with pytest.raises(ValueError, match="multiple of 128"):
+        spike_accum_blocks(
+            jnp.zeros((1, 8)), jnp.array([0]), jnp.zeros((1, 8, 16400)),
+            interpret=True,
+        )
+
+
+@pytest.mark.parametrize("rate", [0.0, 0.002, 0.05, 1.0])
+def test_spike_strips_match_numpy(rate):
+    """The kernel's event list: the ids of the 8-row strips of the stored
+    tiles that hold a spike, ascending, their count and each strip's
+    spike lanes, against a NumPy count on random rasters."""
+    rng = np.random.default_rng(int(rate * 1000))
+    n_blocks, b, k = 4, 256, 6
+    for _ in range(3):
+        s = (rng.random((n_blocks, b)) < rate).astype(np.float32)
+        src = rng.integers(0, n_blocks, size=k)
+        strips, count, masks = spike_strips(jnp.asarray(s), jnp.asarray(src))
+        fired = s[src].reshape(k * b // 8, 8) != 0
+        want = np.flatnonzero(fired.any(axis=1))
+        assert int(count[0]) == want.size
+        np.testing.assert_array_equal(np.asarray(strips)[: want.size], want)
+        np.testing.assert_array_equal(np.asarray(masks), fired @ (1 << np.arange(8)))

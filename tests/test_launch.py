@@ -78,6 +78,7 @@ print("OK")
     monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
     out = run_devices(code, n_devices=n_devices)
     assert "platform=cpu" in out and f"devices={n_devices}" in out
+    assert "weight strips a step" in out
     assert "OK" in out
 
 

@@ -446,6 +446,61 @@ print("OK")
 """
         assert "OK" in run_devices(code)
 
+    def test_accum_stats_counts_the_strips_each_device_receives(self):
+        """``accum_stats`` counts, per device and step, the 8-row strips
+        of its stored tiles (padding tiles included) that hold a spike of
+        the previous step which the exchange delivers: every column of a
+        moved block for ``'sparse'``, and for ``'ragged'`` only the
+        columns another group sends, those with a synapse into the
+        receiving group."""
+        code = """
+import numpy as np, jax
+from repro.snn import DistributedSNN, LIFParams, BlockSynapses
+from jax.sharding import AxisType
+from tests.test_snn_sparse import _clustered_w
+
+m, n_dev, b = 128, 8, 16
+w = _clustered_w(m, n_dev, extra=((0, 1), (2, 5)))
+w[0:b, b:2 * b] = 0.0  # device 1 holds one tile fewer: a padding tile
+w[2 * b:2 * b + 8, 4 * b:6 * b] = 0.0  # a strip of block 2 'ragged' skips on 2x2
+syn = BlockSynapses.from_dense(w, n_dev)
+src_pad, _ = syn.padded()
+k = src_pad.shape[1]
+raster = (np.random.default_rng(1).random((40, m)) < 0.1).astype(np.float32)
+means = {}
+for shape, names, exch in [((8,), ("data",), "sparse"),
+                           ((4, 2), ("pod", "data"), "sparse"),
+                           ((4, 2), ("pod", "data"), "ragged")]:
+    mesh = jax.make_mesh(shape, names, axis_types=(AxisType.Auto,) * len(names))
+    eng = DistributedSNN(mesh=mesh, params=LIFParams(), exchange=exch, syn=syn)
+    r = 1 if len(shape) == 1 else shape[1]
+    counts = []
+    for t in range(40):
+        prev = raster[t - 1] if t else np.zeros(m)
+        for dev in range(n_dev):
+            gd = dev // r
+            into = w[:, gd * r * b:(gd + 1) * r * b] != 0  # synapses into the group
+            n = 0
+            for s in src_pad[dev]:
+                rows = slice(s * b, (s + 1) * b)
+                spikes = prev[rows] != 0
+                gs = s // r
+                if gs != gd and exch == "ragged":
+                    spikes &= into[rows].any(axis=1)
+                elif gs != gd:
+                    spikes &= into[gs * r * b:(gs + 1) * r * b].any()
+                n += spikes.reshape(-1, 8).any(axis=1).sum()
+            counts.append(n)
+    got = eng.accum_stats(raster)
+    assert got["of"] == k * b // 8 and got["max"] == max(counts), (exch, got)
+    assert abs(got["mean"] - np.mean(counts)) < 1e-12, (exch, got)
+    assert 0 < got["mean"] < got["of"]
+    means[exch] = got["mean"]
+assert means["ragged"] < means["sparse"]  # the plan's pruned columns
+print("OK")
+"""
+        assert "OK" in run_devices(code)
+
     def test_ragged_scatter_modes_bit_identical(self):
         """The fused single-``segment_sum`` scatter (ROADMAP item: one
         scatter op per step instead of one per round) is bit-identical

@@ -54,6 +54,7 @@ def _sds(shape, dtype, sharding):
     [
         (1, 16384, 1),  # one chip holding M = 16,384 as one tile
         (4, 8192, 3),  # one chip's share of a 2×2 mesh at M = 32,768
+        (4, 4096, 4),  # one chip's share of the 2×2 cell at M = 16,384
     ],
 )
 def test_spike_accum_blocks_compiles(topo, n_blocks, b, k):
