@@ -4,14 +4,15 @@
 
 For each seed, in one process: the cell's set-up as ``run.py`` makes it,
 one call of the timed chunk at the cell's size, then, with the program's
-state freed, the float64 replay of its raster.  Per seed it prints the
-program's widest gap and the controls' (``reference.CONTROLS``, put in
-the program's place by ``reference.control``): the reference computed in
-bfloat16, float32 with bfloat16 weights, and, for information, plain
-float32.  The last line is a JSON summary: the lower reading (largest
-program gap), each control's smallest gap, the upper reading (the
-smallest of the controls that the cell's limits file requires to fail,
-``controls``) and whether every one of those fails.
+state freed, the float64 replay of its raster by the network family
+(``bench/models/<family>.py``).  Per seed it prints the program's widest
+gap and the family's controls' (``controls``, each the reference in a
+lower precision put in the program's place); for ``brain_model`` the
+reference computed in bfloat16, float32 with bfloat16 weights, and, for
+information, plain float32.  The last line is a JSON summary: the lower
+reading (largest program gap), each control's smallest gap, the upper
+reading (the smallest of the controls that the cell's limits file
+requires to fail, ``controls``) and whether every one of those fails.
 
 With ``--fault <name>`` a fault of ``bench/tests/faults.py`` is planted
 in the program first, and the program's gaps are the fault's readings at
@@ -30,10 +31,7 @@ import numpy as np
 
 sys.path[:0] = [str(Path(__file__).resolve().parents[1]), str(Path(__file__).resolve().parents[1] / "src")]
 
-from bench import reference as ref  # noqa: E402
 from bench import run  # noqa: E402
-
-CONTROLS = dict(ref.CONTROLS, float32={"q": ref.as_float32})
 
 
 def reading(cell: run.Cell, seed: int) -> dict:
@@ -45,15 +43,13 @@ def reading(cell: run.Cell, seed: int) -> dict:
     del compiled, args
     built.engine = None
     gc.collect()
-    n_dev = int(np.prod(cell.config["mesh"]))
-    m = built.net.n_neurons
-    noise64 = ref.noise(key, n_dev, m // n_dev, steps, cell.mix["noise_sigma"], built.lif.dt)
+    fam, net, cfg, mix = built.family, built.net, cell.config, cell.mix
     t = time.perf_counter()
-    v = ref.check(raster, built.net, noise64, built.lif, cell.mix["i_ext"])
+    v = fam.check(raster, net, key, cfg, mix)
     out = {"seed": seed, "gap_mV": v.gap_mV, "flips": v.flips, "spikes": v.spikes,
            "check_s": time.perf_counter() - t}
-    for name, kw in CONTROLS.items():
-        c = ref.control(raster, built.net, noise64, built.lif, cell.mix["i_ext"], **kw)
+    for name, control in fam.controls.items():
+        c = control(raster, net, key, cfg, mix)
         out[f"control_{name}_gap_mV"] = c.gap_mV
         out[f"control_{name}_flips"] = c.flips
     return out
@@ -71,12 +67,13 @@ def main(argv: list[str] | None = None) -> int:
     if args.fault:
         from bench.tests import faults
 
-        faults.install(args.fault)
+        faults.install(args.fault, run.family(cell.config).neuron_step)
     rows = []
     for seed in (int(s) for s in args.seeds.split(",")):
         rows.append(reading(cell, seed))
         print(json.dumps(rows[-1]), flush=True)
-    control_min = {c: min(r[f"control_{c}_gap_mV"] for r in rows) for c in CONTROLS}
+    control_min = {c: min(r[f"control_{c}_gap_mV"] for r in rows)
+                   for c in run.family(cell.config).controls}
     required = cell.limits["controls"]
     print(json.dumps({
         "workload": cell.name,

@@ -11,7 +11,7 @@ pair, gamma(2, w_scale/2) weights, Dale's law with inhibitory
 presynaptic neurons, no self-synapse).  The draw is sparse, so it costs
 milliseconds where sampling dense tiles costs seconds.  The connectome
 comes from the configuration's seed and the weights from the run's
-(``run.network``).
+(``models/brain_model.py:network``).
 
 The program receives the population graph (for its Algorithm-1
 partition) and the ``B × B`` tiles that hold synapses, in the layout that

@@ -7,21 +7,25 @@ A cell ``<config>.<mix>`` of ``BENCHMARK.json`` names a configuration
 (``bench/configs/<config>.json``: network, neuron model, chips, exchange)
 and a traffic mix (``bench/mixes/<mix>.json``: drive, noise and chunk
 length); its comparison limit is ``bench/limits/<cell>.json`` and each
-per-layer metric is read by ``bench/metrics/<metric>.py``.  A run:
+per-layer metric is read by ``bench/metrics/<metric>.py``.  The
+configuration's ``"network"`` names its network family,
+``bench/models/<family>.py`` (``brain_model`` when absent), which makes
+the network, hands it to the program, checks the raster against its own
+reference and counts the work.  A run:
 
 1. fails, printing no result, unless JAX sees TPU chips, exactly as many
    as the cell asks for;
 2. keeps JAX's compilation cache at a fixed path inside the checkout
    (``.jax_cache/``);
-3. makes the network (``network.py``: the configuration's connectome,
-   weights from the seed), hands it to the program (Algorithm-1 partition, block-CSR tiles, ``DistributedSNN``),
-   compiles one chunk of ``chunk_steps`` steps and runs it once: all of
-   this is set-up;
+3. makes the network (the configuration's connectome, weights from the
+   seed), hands it to the program (for ``brain_model``: Algorithm-1
+   partition, block-CSR tiles, ``DistributedSNN``), compiles one chunk of
+   ``chunk_steps`` steps and runs it once: all of this is set-up;
 4. measures a closed loop of chunks for ``--seconds``: each chunk is one
    call of the compiled step, and its raster is on the host before the
    next is dispatched (a user who records spikes);
 5. frees the program's state and replays every distinct raster against
-   the float64 reference (``reference.py``).
+   the family's float64 reference.
 
 With ``--trace 1`` the loop is a few chunks under ``jax.profiler``, and
 the per-layer metrics come from that trace, the work counts and the
@@ -40,6 +44,7 @@ import gc  # noqa: E402
 import hashlib  # noqa: E402
 import importlib.util  # noqa: E402
 import json  # noqa: E402
+import resource  # noqa: E402
 import shutil  # noqa: E402
 import sys  # noqa: E402
 from pathlib import Path  # noqa: E402
@@ -52,8 +57,6 @@ for _p in (ROOT / "src", ROOT):
     if str(_p) not in sys.path:
         sys.path.insert(0, str(_p))
 
-from bench import network as nw  # noqa: E402
-from bench import reference as ref  # noqa: E402
 from bench import trace as tr  # noqa: E402
 from bench import work as wk  # noqa: E402
 
@@ -131,46 +134,33 @@ def seed_key(seed: int):
     return jax.random.PRNGKey(word)
 
 
+FAMILIES: dict[Path, object] = {}  # network family modules, by path
+
+
+def family(cfg: dict):
+    """The module of the configuration's network family,
+    ``bench/models/<cfg["network"]>.py`` under the checkout
+    (``brain_model`` when the configuration names none); what a family
+    module exposes is in ``bench/models/brain_model.py``'s docstring."""
+    path = ROOT / "bench" / "models" / f"{cfg.get('network', 'brain_model')}.py"
+    if path not in FAMILIES:
+        if not path.exists():
+            raise SystemExit(f"no network family {path}")
+        spec = importlib.util.spec_from_file_location("bench_model_" + path.stem, path)
+        mod = importlib.util.module_from_spec(spec)
+        sys.modules[spec.name] = mod  # for its dataclasses
+        spec.loader.exec_module(mod)
+        FAMILIES[path] = mod
+    return FAMILIES[path]
+
+
 @dataclasses.dataclass
 class Built:
     engine: object
-    net: nw.Network
+    net: object  # the family's network: n_neurons, dt
     build_s: float  # the program's build: partition, tiling, engine
     network_s: float  # making the network and its tiles (benchmark)
-    lif: ref.Lif
-
-
-def network(cfg: dict, seed: int) -> tuple[nw.Network, float]:
-    """The configuration's network in the layout of the program's
-    partition, and the seconds that partition took.
-
-    The connectome (populations, which neuron pairs are synapses, which
-    neurons are inhibitory) and the partition come from the
-    configuration's ``connectome_seed``; the run's ``seed`` draws the
-    synaptic weights.  So every seed gives the program the same tile
-    occupancy, exchange plan and compiled step, with other weights and
-    other spikes.
-    """
-    from repro.core import build_graph, greedy_partition
-
-    rng = np.random.default_rng(cfg["connectome_seed"])
-    pops = nw.brain_model(rng, n_populations=cfg["populations"], **cfg["model"])
-    syn = nw.synapses(rng, np.random.default_rng(seed), pops.pair_probs(),
-                      cfg["neurons_per_pop"], **cfg["synapses"])
-    n_dev = int(np.prod(cfg["mesh"]))
-    if syn.n_neurons != n_dev * cfg["neurons_per_device"]:
-        raise ValueError(f"{syn.n_neurons} neurons over {n_dev} chips is not "
-                         f"{cfg['neurons_per_device']} a chip")
-
-    t = time.perf_counter()
-    graph = build_graph(pops.src, pops.dst, pops.prob, pops.weights)
-    part = greedy_partition(graph, n_dev, seed=cfg["connectome_seed"])
-    partition_s = time.perf_counter() - t
-
-    order = nw.layout(nw.equal_blocks(part.assign, n_dev), cfg["neurons_per_pop"])
-    position_of = np.empty_like(order)
-    position_of[order] = np.arange(order.shape[0])
-    return nw.Network.from_synapses(syn, position_of), partition_s
+    family: object  # the network family's module
 
 
 def build(cell: Cell, seed: int) -> Built:
@@ -179,30 +169,20 @@ def build(cell: Cell, seed: int) -> Built:
     from jax.sharding import AxisType
 
     from repro.kernels import KernelPolicy
-    from repro.snn import BlockSynapses, DistributedSNN, LIFParams
 
     cfg, mix = cell.config, cell.mix
+    fam = family(cfg)
     n_dev = int(np.prod(cfg["mesh"]))
     t0 = time.perf_counter()
-    net, build_s = network(cfg, seed)
-    src, dst, blocks = net.tiles(n_dev)
+    net, build_s = fam.make(cfg, mix, seed, n_dev)
 
     t = time.perf_counter()
     network_s = t - t0 - build_s
-    tiles = BlockSynapses.from_tiles(src, dst, blocks, n_dev)
-    del blocks
     mesh = jax.make_mesh(tuple(cfg["mesh"]), ("pod", "data"), axis_types=(AxisType.Auto,) * 2)
-    engine = DistributedSNN(
-        mesh=mesh,
-        params=LIFParams(**cfg["lif"], noise_sigma=mix["noise_sigma"]),
-        exchange=cfg["exchange"],
-        i_ext=mix["i_ext"],
-        syn=tiles,
-        policy=KernelPolicy(use_pallas=jax.devices()[0].platform == PLATFORM),
-    )
+    policy = KernelPolicy(use_pallas=jax.devices()[0].platform == PLATFORM)
+    engine = fam.hand_off(cfg, mix, net, mesh, policy)
     build_s += time.perf_counter() - t
-    return Built(engine=engine, net=net, build_s=build_s, network_s=network_s,
-                 lif=ref.Lif(**cfg["lif"]))
+    return Built(engine=engine, net=net, build_s=build_s, network_s=network_s, family=fam)
 
 
 @dataclasses.dataclass
@@ -212,6 +192,7 @@ class Window:
     errors: int
     seconds: float
     compiles: int
+    chunk_s: list[float]  # each chunk's wall time, dispatch to raster on the host
 
 
 def closed_loop(compiled, args, *, seconds: float = 0.0, chunks: int = 0) -> Window:
@@ -226,7 +207,7 @@ def closed_loop(compiled, args, *, seconds: float = 0.0, chunks: int = 0) -> Win
             compiles.append(event)
 
     jax.monitoring.register_event_duration_secs_listener(on_event)
-    rasters, attempted, errors = [], 0, 0
+    rasters, attempted, errors, chunk_s = [], 0, 0, []
     t_start = time.perf_counter()
     t_end = t_start
     try:
@@ -240,10 +221,12 @@ def closed_loop(compiled, args, *, seconds: float = 0.0, chunks: int = 0) -> Win
             except Exception as e:  # a failed chunk is counted, the loop goes on
                 errors += 1
                 print(f"chunk {attempted} failed: {e!r}", file=sys.stderr)
-            t_end = time.perf_counter()
+            t = time.perf_counter()
+            chunk_s.append(t - t_end)
+            t_end = t
     finally:
         jax.monitoring.unregister_event_duration_listener(on_event)
-    return Window(rasters, attempted, errors, t_end - t_start, len(compiles))
+    return Window(rasters, attempted, errors, t_end - t_start, len(compiles), chunk_s)
 
 
 def read_metric(name: str, run) -> float | None:
@@ -316,8 +299,8 @@ def main(argv: list[str] | None = None) -> int:
     failed = win.errors + len(win.rasters) - len(valid)
     distinct = {hashlib.sha1(r.tobytes()).hexdigest(): r for r in valid}
     n_dev = int(np.prod(cell.config["mesh"]))
-    noise64 = ref.noise(key, n_dev, m // n_dev, steps, cell.mix["noise_sigma"], built.lif.dt)
-    verdicts = [ref.check(r, built.net, noise64, built.lif, cell.mix["i_ext"]) for r in distinct.values()]
+    verdicts = [built.family.check(r, built.net, key, cell.config, cell.mix)
+                for r in distinct.values()]
     gap = max((v.gap_mV for v in verdicts), default=float("inf"))
     checks = {
         "gap_mV": {"value": gap, "limit": cell.limits["gap_mV"]},
@@ -329,8 +312,11 @@ def main(argv: list[str] | None = None) -> int:
     rate = spikes / max(1, len(valid) * steps * m)
     print(f"cell {cell.name} seed {args.seed}: {len(valid)} chunks of {steps} steps, "
           f"{len(distinct)} distinct raster(s), {spikes} spikes")
-    print(f"rate: {rate} spikes per neuron per step ({rate / built.lif.dt * 1e3} Hz)")
+    print(f"rate: {rate} spikes per neuron per step ({rate / built.net.dt * 1e3} Hz)")
     print(f"window: {win.seconds} s, compilations inside it: {win.compiles}")
+    print(f"wall time a chunk: median {np.median(win.chunk_s)} s, slowest {max(win.chunk_s)} s "
+          f"(chunk {int(np.argmax(win.chunk_s)) + 1}); host peak resident memory "
+          f"{resource.getrusage(resource.RUSAGE_SELF).ru_maxrss / 2**20} GiB")
 
     metrics = {}
     if args.trace:
@@ -340,7 +326,7 @@ def main(argv: list[str] | None = None) -> int:
             compile_s=compile_s,
             steps=steps * len(valid),
             reduction=red,
-            work=wk.count(valid, built.net.per_block(n_dev)),
+            work=built.family.work(valid, built.net, n_dev),
             peaks=wk.peaks(device["kind"]),
         )
         for spec in cell.per_layer:

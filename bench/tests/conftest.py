@@ -21,13 +21,16 @@ TINY = {"populations": 32, "neurons_per_pop": 16}
 
 
 def write_small_root(root: Path, mixes: dict[str, dict] | None = None) -> Path:
-    """A checkout-like directory: ``BENCHMARK.json`` with the real metrics
-    and cells ``tiny_1chip.<mix>`` and ``tiny_2x2.<mix>`` of 512 neurons,
-    for the real mixes and any in ``mixes``, each with the limits of
-    ``brain16k_1chip.<mix>`` (of ``brain16k_1chip.async`` for a new mix)."""
+    """A checkout-like directory: the network families of ``bench/models/``,
+    ``BENCHMARK.json`` with the real metrics and cells ``tiny_1chip.<mix>``
+    and ``tiny_2x2.<mix>`` of 512 neurons, for the real mixes and any in
+    ``mixes``, each with the limits of ``brain16k_1chip.<mix>`` (of
+    ``brain16k_1chip.async`` for a new mix)."""
     bench = root / "bench"
-    for d in ("configs", "mixes", "limits"):
+    for d in ("configs", "mixes", "limits", "models"):
         (bench / d).mkdir(parents=True, exist_ok=True)
+    for p in (ROOT / "bench" / "models").glob("*.py"):
+        (bench / "models" / p.name).write_text(p.read_text())
     spec = json.loads((ROOT / "BENCHMARK.json").read_text())
     base = json.loads((ROOT / "bench" / "configs" / "brain16k_1chip.json").read_text())
     for name, mesh, exchange in (("tiny_1chip", [1, 1], "sparse"), ("tiny_2x2", [2, 2], "ragged")):
@@ -63,7 +66,7 @@ from bench.tests import faults
 run.ROOT = Path({small!r})
 if {skip_chip!r}:
     run.require_chips = lambda n: {{"platform": "cpu", "kind": "cpu", "count": n}}
-faults.install({fault!r})
+faults.install({fault!r}, run.family(run.load_cell({workload!r}).config).neuron_step)
 sys.exit(run.main({argv!r}))
 """
 
@@ -73,7 +76,7 @@ def run_cell(small: Path, workload: str, *, seed: int = 11, seconds: float = 1.0
     """Run ``bench/run.py``'s ``main`` in a subprocess; returns
     ``(returncode, result or None, stdout, stderr)``."""
     code = DRIVER.format(root=str(ROOT), src=str(ROOT / "src"), small=str(small),
-                         skip_chip=skip_chip, fault=fault,
+                         skip_chip=skip_chip, fault=fault, workload=workload,
                          argv=["--workload", workload, "--seed", str(seed),
                                "--seconds", str(seconds), "--trace", "0"])
     env = dict(os.environ, JAX_PLATFORMS="cpu",

@@ -1,5 +1,6 @@
 """Faults planted under the timed path, for ``test_harness.py``: each must
-turn ``correct`` false.
+turn ``correct`` false.  The neuron faults go into the program's neuron
+update that the network family names (its module's ``neuron_step``).
 
 * ``state_unchanged``: the neuron step returns its state as it came, and
   no spikes;
@@ -12,7 +13,7 @@ turn ``correct`` false.
 from __future__ import annotations
 
 
-def install(name: str | None) -> None:
+def install(name: str | None, neuron_step: str) -> None:
     if name is None:
         return
     import jax
@@ -21,15 +22,16 @@ def install(name: str | None) -> None:
     from repro.snn import distributed
 
     if name == "state_unchanged":
-        distributed.lif_step = lambda state, i_syn, params: (state, jnp.zeros_like(state.v))
+        setattr(distributed, neuron_step,
+                lambda state, i_syn, params: (state, jnp.zeros_like(state.v)))
     elif name == "half_left_out":
-        step = distributed.lif_step
+        step = getattr(distributed, neuron_step)
 
         def half(state, i_syn, params):
             state, spikes = step(state, i_syn, params)
             return state, spikes.at[spikes.shape[0] // 2:].set(0.0)
 
-        distributed.lif_step = half
+        setattr(distributed, neuron_step, half)
     elif name == "no_exchange":
         distributed.jax.lax.ppermute = lambda x, axis_name, perm: jnp.zeros_like(x)
     elif name == "altered":

@@ -2,7 +2,7 @@
 (``v5e:2x2``): one device for the one-chip configuration, the 2×2 mesh
 for the other, at the cells' real sizes (M = 16,384, 1,000-step chunks)
 and with the exchange schedule of the configuration's connectome, which
-every seed shares (``run.network``).  No
+every seed shares (``brain_model.network``).  No
 chip is needed; the topology is described inside a module-scoped
 fixture, so only the worker that runs this file loads the TPU compiler.
 """
@@ -74,7 +74,7 @@ def test_chunk_step_compiles(topo, cell):
 
     spec = run.load_cell(cell)
     cfg = spec.config
-    net, _ = run.network(cfg, SEED)
+    net, _ = run.family(cfg).network(cfg, SEED)
     n_dev = int(np.prod(cfg["mesh"]))
     m, b = net.n_neurons, net.n_neurons // n_dev
     tiles = TileOccupancy(net, n_dev)

@@ -19,22 +19,21 @@ def program_raster(small_root, cell_name: str, seed: int):
     steps = int(cell.mix["chunk_steps"])
     compiled, args, _ = built.engine.compile(steps, key=key)
     raster = np.asarray(compiled(*args))
-    noise64 = ref.noise(key, 1, built.net.n_neurons, steps, cell.mix["noise_sigma"], built.lif.dt)
-    return raster, built, noise64, cell
+    return raster, built, key, cell
 
 
 @pytest.mark.parametrize("cell_name", ["tiny_1chip.async", "tiny_1chip.tonic"])
 @pytest.mark.parametrize("seed", [1, 2**40 + 3])
 def test_program_passes_and_control_fails(small_root, cell_name, seed):
-    raster, built, noise64, cell = program_raster(small_root, cell_name, seed)
-    i_ext = cell.mix["i_ext"]
+    raster, built, key, cell = program_raster(small_root, cell_name, seed)
+    args = (built.net, key, cell.config, cell.mix)
     limit = cell.limits["gap_mV"]
-    v = ref.check(raster, built.net, noise64, built.lif, i_ext)
+    v = built.family.check(raster, *args)
     assert v.spikes > 0
     assert v.gap_mV <= limit
     assert "bfloat16" in cell.limits["controls"]
     for name in cell.limits["controls"]:
-        c = ref.control(raster, built.net, noise64, built.lif, i_ext, **ref.CONTROLS[name])
+        c = built.family.controls[name](raster, *args)
         assert c.gap_mV > limit, name
 
 
@@ -44,11 +43,11 @@ def test_tonic_requires_bfloat16_weights_to_fail(small_root):
 
 
 def test_refractory_spike_is_infinite_gap(small_root):
-    raster, built, noise64, cell = program_raster(small_root, "tiny_1chip.tonic", 4)
+    raster, built, key, cell = program_raster(small_root, "tiny_1chip.tonic", 4)
     t, i = map(int, np.argwhere(raster > 0)[0])
     bad = raster.copy()
     bad[t + 1, i] = 1.0  # the step after a spike is refractory
-    v = ref.check(bad, built.net, noise64, built.lif, cell.mix["i_ext"])
+    v = built.family.check(bad, built.net, key, cell.config, cell.mix)
     assert v.gap_mV == float("inf")
 
 

@@ -43,7 +43,7 @@ def test_counts_match_brute_force(n_blocks):
                     ops[d] += 2 * n_syn
                     if n_syn and i // b != d:
                         xbytes[d] += 4
-    w = wk.count(rasters, net.per_block(n_blocks))
+    w = wk.count(rasters, net.per_block(n_blocks), state_bytes=20, id_bytes=4)
     assert w.steps == 60
     np.testing.assert_array_equal(w.accum_ops, ops)
     np.testing.assert_array_equal(w.accum_bytes, 2 * ops)
@@ -89,8 +89,9 @@ def test_seed_draws_weights_not_connectome():
     cfg = json.loads((ROOT / "bench" / "configs" / "brain16k_2x2.json").read_text())
     cfg.update(populations=32, neurons_per_pop=16, neurons_per_device=128)
     cfg["model"] = dict(cfg["model"], n_regions=8)
-    a, _ = run.network(cfg, 1)
-    b, _ = run.network(cfg, 2**40 + 7)
+    network = run.family(cfg).network
+    a, _ = network(cfg, 1)
+    b, _ = network(cfg, 2**40 + 7)
     np.testing.assert_array_equal(a.indptr, b.indptr)
     np.testing.assert_array_equal(a.post, b.post)
     np.testing.assert_array_equal(np.sign(a.w), np.sign(b.w))

@@ -8,9 +8,10 @@ implementation moves, so they read the same whatever runs the step:
   step, on the chip that holds its postsynaptic neuron, is read once
   (4 bytes) and multiplied and added (2 operations);
 * neuron update: each chip reads and writes the state of its neurons
-  (v and u in, v, u and the spike out: 20 bytes a neuron);
+  (``state_bytes`` a neuron, which the network family states);
 * exchange: a spike reaches every other chip that holds one of its
-  synapses, as a 4-byte neuron id, over that chip's interconnect.
+  synapses, as an ``id_bytes`` neuron id (the family's), over that
+  chip's interconnect.
 
 The least time is the larger of operations over peak FLOP/s, bytes over
 HBM bandwidth and, on several chips, received bytes over ICI bandwidth,
@@ -25,8 +26,6 @@ from pathlib import Path
 import numpy as np
 
 PEAKS = Path(__file__).resolve().parent / "peaks.json"
-
-STATE_BYTES = 20  # v, u read; v, u, spike written (float32)
 
 
 def peaks(device_kind: str, path: Path = PEAKS) -> dict:
@@ -61,10 +60,12 @@ class Work:
         ])
 
 
-def count(rasters: list[np.ndarray], per_block: np.ndarray) -> Work:
+def count(rasters: list[np.ndarray], per_block: np.ndarray, *, state_bytes: float,
+          id_bytes: float) -> Work:
     """Work of the steps in ``rasters`` (chunks ``[T, M]``, each starting
     from rest): ``per_block[i, d]`` is the number of synapses of neuron
-    ``i`` onto chip ``d``'s neurons."""
+    ``i`` onto chip ``d``'s neurons; a neuron's state moves
+    ``state_bytes`` a step, a spike ``id_bytes`` to each other chip."""
     m, n = per_block.shape
     b = m // n
     fired = np.zeros(m)  # spikes that drive a step: every row but the last
@@ -79,6 +80,6 @@ def count(rasters: list[np.ndarray], per_block: np.ndarray) -> Work:
         steps=steps,
         accum_ops=2.0 * uses,
         accum_bytes=4.0 * uses,
-        state_bytes=np.full(n, float(STATE_BYTES * b * steps)),
-        exchange_bytes=4.0 * (fired @ remote),
+        state_bytes=np.full(n, float(state_bytes * b * steps)),
+        exchange_bytes=id_bytes * (fired @ remote),
     )
