@@ -12,7 +12,8 @@ with the tracer (:mod:`repro.obs`) on: its planner and executor spans
 and the device's ops land in one profile, on one clock.  After the timed
 run the launcher prints how many 8-row weight strips the accumulation
 streamed a step (``DistributedSNN.accum_stats``, counter
-``snn.accum_strips``).
+``snn.accum_strips``), and on several devices the live level-2 rounds
+(``DistributedSNN.level2_rounds``, counter ``snn.level2_rounds``).
 
 On the CPU, with fake host devices for the mesh:
 
@@ -213,6 +214,9 @@ def _launch(args: argparse.Namespace) -> Launch:
     )
     vol = eng.exchange_stats()
     print("slow-axis bytes/step: " + "  ".join(f"{k}={v}" for k, v in sorted(vol.items())))
+    if eng.exchange == "ragged":
+        rounds = eng.level2_rounds()
+        print("level-2 rounds: " + "  ".join(f"{k}={v}" for k, v in sorted(rounds.items())))
     return Launch(
         engine=eng,
         raster=raster,

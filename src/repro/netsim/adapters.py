@@ -96,9 +96,10 @@ def flat_rounds(
 
 
 def ragged_rounds(plan) -> list[list[Message]]:
-    """Replay a :class:`~repro.snn.ragged.RaggedPlan`'s executed
-    bridge-to-bridge schedule; total bytes equal ``plan.bytes_per_step``
-    (= ``exchange_volume(..., plan=plan)['ragged']``, padding included).
+    """Replay a :class:`~repro.snn.ragged.RaggedPlan`'s bridge-to-bridge
+    schedule with its packed payloads; total bytes equal
+    ``plan.bytes_per_step`` (= ``exchange_volume(..., plan=plan)['ragged']``,
+    padding included).
     """
     return rounds_from_triples(plan.round_messages(), tag="ragged")
 

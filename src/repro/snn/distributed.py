@@ -18,8 +18,8 @@ per device, and the per-step spike exchange follows either
   (:mod:`repro.snn.sparse`), or
 * ``exchange='ragged'``    — the **bridge-compacted, column-pruned**
   exchange (:mod:`repro.snn.ragged`): each scheduled cross-group pair
-  moves one packed ``f32[K_r]`` payload (only the consumed source
-  columns, padded to the per-round max) from the sending group's bridge
+  moves its sending group's ``f32[R·B]`` block, with the source columns
+  no receiver consumes masked to zero, from the sending group's bridge
   device straight to the receiving group's bridge, which re-broadcasts
   it over the fast axis — eliminating the ``R×`` inner-position
   redundancy ``'sparse'`` still carries, exactly the paper's
@@ -155,15 +155,6 @@ class DistributedSNN:
         device per destination group (``exchange='ragged'``); ``None``
         spreads bridge duty round-robin.  Derive from an Algorithm-2
         table with :func:`repro.snn.ragged.bridge_inner_from_table`.
-      ragged_scatter: how the ragged executor lands received payloads in
-        the block buffer — ``'fused'`` (default) concatenates every
-        round's payload and indices and runs ONE
-        ``jax.ops.segment_sum`` over all rounds (the ROADMAP's
-        fused-scatter item: one scatter op per step instead of one per
-        round); ``'per_round'`` keeps the original per-round
-        ``buf.at[...].add``.  Bit-identical (each non-trash slot
-        receives at most one contribution, so no reassociation) —
-        pinned by ``test_ragged_scatter_modes_bit_identical``.
     """
 
     mesh: Mesh
@@ -174,7 +165,6 @@ class DistributedSNN:
     syn: BlockSynapses | None = None
     policy: KernelPolicy = KernelPolicy()
     bridge_inner: np.ndarray | None = None
-    ragged_scatter: str = "fused"
     plan: RaggedPlan | None = None
 
     def __post_init__(self):
@@ -183,8 +173,6 @@ class DistributedSNN:
                 raise ValueError("params is required")
             if self.exchange not in ("flat", "two_level", "sparse", "ragged"):
                 raise ValueError(self.exchange)
-            if self.ragged_scatter not in ("fused", "per_round"):
-                raise ValueError(self.ragged_scatter)
             if self.exchange == "two_level" and len(self.mesh.axis_names) < 2:
                 raise ValueError("two_level exchange needs a 2-D mesh")
             if self.w_syn is None and self.syn is None:
@@ -257,22 +245,18 @@ class DistributedSNN:
         """Static signature of the compiled sparse/ragged step.
 
         Two engines with equal signatures (and equal mesh / params /
-        policy) share one compiled step — array contents (spike index
-        rows, synapse tiles) are jit inputs, so a plan swap that keeps
-        the signature flips between steps without a recompile stall.
-        For ``'ragged'`` the signature is the live rounds' (shift,
-        width, ppermute perm); for ``'sparse'`` the masked round pair
-        lists.
+        policy) share one compiled step — array contents (column masks,
+        synapse tiles) are jit inputs, so a plan swap that keeps the
+        signature flips between steps without a recompile stall.  For
+        ``'ragged'`` the signature is the live rounds' (shift, ppermute
+        perm): every round moves ``R·B`` lanes a pair, whatever its
+        columns; for ``'sparse'`` the masked round pair lists.
         """
         if self.exchange == "ragged":
             plan = self._ragged_plan()
             return (
                 "ragged",
-                tuple(
-                    (rnd.shift, rnd.width, rnd.perm)
-                    for rnd in plan.rounds
-                    if rnd.pairs
-                ),
+                tuple((rnd.shift, rnd.perm) for rnd in plan.rounds if rnd.pairs),
             )
         syn = self._block_synapses()
         g, r = self._mesh_groups()
@@ -287,7 +271,9 @@ class DistributedSNN:
     def exchange_stats(self) -> dict[str, int]:
         """Per-step slow-axis receive volume (bytes): the dense schedule
         vs the block-mask-driven one (``exchange='sparse'``) vs the
-        bridge-compacted column-pruned one (``exchange='ragged'``)."""
+        bridge-compacted column-pruned one (``'ragged'``: the plan's
+        packed payloads, what a fabric priced by the byte carries; the
+        executor moves :attr:`~repro.snn.ragged.RaggedPlan.block_bytes_per_step`)."""
         syn = self._block_synapses()
         g, r = self._mesh_groups()
         return exchange_volume(
@@ -296,6 +282,13 @@ class DistributedSNN:
             block_bytes=syn.block_size * 4,
             plan=self._ragged_plan(),
         )
+
+    def level2_rounds(self) -> dict[str, int]:
+        """Live level-2 rounds of the ragged plan, ``{"block": n}``:
+        every round ships its pairs' masked group blocks."""
+        if self.exchange != "ragged":
+            raise ValueError("level2_rounds covers exchange='ragged'")
+        return {"block": sum(1 for rnd in self._ragged_plan().rounds if rnd.pairs)}
 
     def run(self, n_steps: int, *, key: jax.Array | None = None) -> jax.Array:
         """Simulate; returns the global spike raster ``[T, M]``."""
@@ -371,9 +364,12 @@ class DistributedSNN:
         :meth:`run` does; ``compile_s`` is the wall time of lowering and
         compiling alone (input staging excluded).  While the tracer
         (:mod:`repro.obs`) is on, staging, lowering and compiling are the
-        spans ``snn.stage``, ``snn.lower`` and ``snn.compile``, and the
+        spans ``snn.stage``, ``snn.lower`` and ``snn.compile``, the
         counter ``snn.exchange_bytes`` records the slow-axis bytes a step
-        moves (``{"level2": exchange_stats()[exchange]}``).
+        moves (``{"level2": n}``: ``exchange_stats()['sparse']``, or the
+        plan's ``block_bytes_per_step`` for ``'ragged'``), and for
+        ``'ragged'`` the counter ``snn.level2_rounds`` the live rounds
+        (:meth:`level2_rounds`).
         """
         if self.exchange not in ("sparse", "ragged"):
             raise ValueError("compile covers exchange='sparse'/'ragged'")
@@ -386,9 +382,14 @@ class DistributedSNN:
             compiled = lowered.compile()
         compile_s = time.perf_counter() - t
         if obs.is_enabled():
-            # slow-axis bytes a step of the plan just compiled
-            obs.counter("snn.exchange_bytes",
-                        {"level2": self.exchange_stats()[self.exchange]}, tid="snn")
+            # slow-axis bytes a step of the plan just compiled moves
+            if self.exchange == "ragged":
+                moved = self._ragged_plan().block_bytes_per_step
+            else:
+                moved = self.exchange_stats()["sparse"]
+            obs.counter("snn.exchange_bytes", {"level2": moved}, tid="snn")
+            if self.exchange == "ragged":
+                obs.counter("snn.level2_rounds", self.level2_rounds(), tid="snn")
         return compiled, args, compile_s
 
     def _step_key(self, n_steps: int) -> "_StepKey":
@@ -397,7 +398,6 @@ class DistributedSNN:
             params=self.params,
             policy=self.policy,
             i_ext=float(self.i_ext),
-            ragged_scatter=self.ragged_scatter,
             n_steps=int(n_steps),
             signature=self.step_signature(),
         )
@@ -410,7 +410,7 @@ class DistributedSNN:
         The step is built (and cached) by :func:`_sparse_step` keyed on
         the engine's static signature; this method only prepares the jit
         *inputs* — neuron state, padded synapse tiles, and the per-round
-        spike index rows.  Swapping to a plan with an equal
+        column masks.  Swapping to a plan with an equal
         :meth:`step_signature` therefore reuses the compiled step.
         Shared by :meth:`run` (executes), :meth:`compile` (compiles
         ahead of time) and :meth:`trace_step` (abstractly traces —
@@ -426,18 +426,8 @@ class DistributedSNN:
             obs.metric_inc("snn.step_cache_hits")
         with obs.span("snn.stage", cat="exec", tid="snn"):
             src_pad, blk_pad = syn.padded()  # [n_dev, K], [n_dev, K, B, B]
-            if self.exchange == "ragged":
-                plan = self._ragged_plan()
-                # per-device (send, recv) index rows, one [n_dev, 2, K_r]
-                # array per live round (round widths differ — static shapes
-                # per ppermute, not across them)
-                idx_arrays = tuple(
-                    jnp.asarray(np.stack([rnd.send_idx, rnd.recv_idx], axis=1))
-                    for rnd in plan.rounds
-                    if rnd.pairs
-                )
-            else:
-                idx_arrays = ()
+            # per-device column masks, one [n_dev, R·B] array per live round
+            masks = self._ragged_plan().col_masks() if self.exchange == "ragged" else ()
             # one key per device over the full mesh (see the dense path)
             keys = jax.random.split(key, n_dev)
             st0 = init_state(syn.n_neurons, self.params, key)
@@ -451,8 +441,8 @@ class DistributedSNN:
             # the whole tile array on the first device)
             src_arr = jax.device_put(src_pad.astype(np.int32), blk_sharding)
             blk_arr = jax.device_put(blk_pad, blk_sharding)
-            idx_put = tuple(jax.device_put(a, blk_sharding) for a in idx_arrays)
-            args = (v0, u0, keys, src_arr, blk_arr, idx_put)
+            masks_put = tuple(jax.device_put(a, blk_sharding) for a in masks)
+            args = (v0, u0, keys, src_arr, blk_arr, masks_put)
             if obs.is_enabled():  # the span ends when the arrays are resident
                 jax.block_until_ready(args)
         return fn, args
@@ -528,7 +518,6 @@ class _StepKey:
     params: LIFParams | IzhikevichParams
     policy: KernelPolicy
     i_ext: float
-    ragged_scatter: str
     n_steps: int
     signature: tuple
 
@@ -543,16 +532,16 @@ def _sparse_step(key: _StepKey):
     * ``'sparse'`` — only the ``ppermute`` rounds the group-pooled
       block mask schedules run, every inner position shipping the
       full ``R·B`` group block;
-    * ``'ragged'`` — each scheduled pair moves one packed ``f32[K_r]``
-      payload (consumed columns only, padded to the per-round max)
+    * ``'ragged'`` — each scheduled pair moves its sending group's
+      ``f32[R·B]`` block times the round's 0/1 column mask
       bridge-to-bridge via a joint-axis ``ppermute``, then a fast-axis
-      ``psum`` re-broadcasts it inside the receiving group and the
-      payload is scattered back into its block slots (pad lanes land in
-      a trash slot).
+      ``psum`` re-broadcasts it inside the receiving group, which
+      writes it into its buffer row whole.
 
-    Unneeded group blocks/columns never cross the slow axis — their
-    receive slots stay zero, and the block-CSR storage holds no weight
-    for them, so the raster is identical to the dense oracle.  All
+    Unneeded group blocks never cross the slow axis and unneeded columns
+    cross as zeros — their receive slots stay zero, and the block-CSR
+    storage holds no weight for them, so the raster is identical to the
+    dense oracle.  All
     shapes and both schedules are static; the accumulation runs through
     :func:`repro.kernels.spike_currents_blocks` so ``policy`` flips
     einsum ↔ Pallas without touching the exchange.
@@ -563,7 +552,7 @@ def _sparse_step(key: _StepKey):
 
     The ``lru_cache`` is what makes the double-buffered plan swap
     stall-free: engines whose plans share a signature get the *same*
-    jitted callable, and the per-round index rows / synapse tiles are
+    jitted callable, and the per-round column masks / synapse tiles are
     inputs, so flipping plans never rebuilds or recompiles the step.
     """
     mesh = key.mesh
@@ -578,7 +567,6 @@ def _sparse_step(key: _StepKey):
     policy = key.policy
     step = lif_step if isinstance(params, LIFParams) else izhikevich_step
     i_ext = jnp.float32(key.i_ext)
-    fused = key.ragged_scatter == "fused"
     n_steps = key.n_steps
     vec_spec = P(axes)
 
@@ -610,33 +598,21 @@ def _sparse_step(key: _StepKey):
         with jax.named_scope(UNPACK):
             return buf.reshape(n_dev, rb // r)
 
-    def gather_blocks_ragged(spikes_loc, idx_loc):
-        """Ragged level-2: bridge-only packed ppermute + fast-axis
-        broadcast + scatter into block slots (trash slot ``rb``).
-
-        The scatter runs in one of two modes: ``'per_round'`` lands
-        each round's payload with its own ``buf.at[...].add``;
-        ``'fused'`` collects every round's payload and flat buffer
-        indices and lands them all (plus the local group block) in a
-        single ``segment_sum`` — one scatter op per step.  Every
-        non-trash slot receives at most one contribution (rows are
-        disjoint per shift, columns unique within a round), so the
-        two modes are bit-identical.
-        """
+    def gather_blocks_ragged(spikes_loc, masks_loc):
+        """Ragged level-2: per live round, the group block times the
+        round's column mask moves by the bridge-only joint-axis ppermute
+        and the fast-axis broadcast, and is written into the block
+        buffer's row ``(gid - shift) % G``; the local group block is
+        written into row ``gid``.  No gather, no scatter."""
         s_grp = gather_group(spikes_loc)
         rb = s_grp.shape[0]
         gid = jax.lax.axis_index(slow)
         with jax.named_scope(UNPACK):
-            parts = [s_grp]  # local block → own row, columns [0, rb)
-            flat_idx = [gid * (rb + 1) + jnp.arange(rb, dtype=jnp.int32)]
-            buf = None
-            if not fused:
-                buf = jnp.zeros((g, rb + 1), jnp.float32)
-                buf = buf.at[gid, :rb].set(s_grp)
-        for (shift, _width, perm), idx in zip(schedule, idx_loc):
+            buf = jnp.zeros((g, rb), jnp.float32)
+            buf = jax.lax.dynamic_update_slice(buf, s_grp[None], (gid, 0))
+        for (shift, perm), mask in zip(schedule, masks_loc):
             with jax.named_scope(PACK):
-                send_idx = idx[0, 0]  # [K_r] columns of s_grp to pack
-                payload = s_grp[send_idx]
+                payload = s_grp * mask[0]  # [rb], unconsumed columns zeroed
             with jax.named_scope(SEND):
                 recv = jax.lax.ppermute(payload, axes, perm=perm)
                 if r > 1:
@@ -644,21 +620,10 @@ def _sparse_step(key: _StepKey):
                     # holds zeros, so a psum is the intra-group broadcast
                     recv = jax.lax.psum(recv, inner)
             with jax.named_scope(UNPACK):
-                recv_idx = idx[0, 1]  # [K_r] slots (rb = trash)
                 row = (gid - shift) % g
-                if fused:
-                    parts.append(recv)
-                    flat_idx.append(row * (rb + 1) + recv_idx)
-                else:
-                    buf = buf.at[row, recv_idx].add(recv)
+                buf = jax.lax.dynamic_update_slice(buf, recv[None], (row, 0))
         with jax.named_scope(UNPACK):
-            if fused:
-                buf = jax.ops.segment_sum(
-                    jnp.concatenate(parts),
-                    jnp.concatenate(flat_idx),
-                    num_segments=g * (rb + 1),
-                ).reshape(g, rb + 1)
-            return buf[:, :rb].reshape(n_dev, rb // r)
+            return buf.reshape(n_dev, rb // r)
 
     @functools.partial(
         shard_map,
@@ -667,7 +632,7 @@ def _sparse_step(key: _StepKey):
         out_specs=P(None, axes),
         check_vma=False,
     )
-    def _run(v0, u0, keys, src_ids, blocks, idx_loc):
+    def _run(v0, u0, keys, src_ids, blocks, masks_loc):
         state = NeuronState(v=v0, u=u0, key=keys[0])
         src_ids_loc = src_ids[0]  # [K]
         blocks_loc = blocks[0]  # [K, B, B]
@@ -676,7 +641,7 @@ def _sparse_step(key: _StepKey):
         def body(carry, _):
             state, prev_loc = carry
             if ragged:
-                s_blocks = gather_blocks_ragged(prev_loc, idx_loc)
+                s_blocks = gather_blocks_ragged(prev_loc, masks_loc)
             else:
                 s_blocks = gather_blocks(prev_loc)
             with jax.named_scope(ACCUMULATE):

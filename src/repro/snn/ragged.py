@@ -21,12 +21,31 @@ schedule**:
   over the fast axis (level-1 territory, like the paper's bridge fan-out).
 * **Static shapes** — SPMD needs one trace, so payloads are padded to the
   per-round maximum width ``K_r``; pad lanes are routed to a trash slot
-  on the receive side.  The executed (= accounted) bytes per round are
-  ``|pairs_r| · K_r · 4``.
+  on the receive side.  The packed bytes per round are
+  ``|pairs_r| · K_r · 4``: what a fabric priced by the byte carries
+  (:mod:`repro.netsim` replays :meth:`RaggedPlan.round_messages`, and
+  the out-of-core planner sums them).
 
-The executor lives in :meth:`repro.snn.distributed.DistributedSNN`
-(``exchange='ragged'``); :func:`repro.snn.sparse.exchange_volume` reports
-the resulting byte accounting next to the flat and sparse schedules.
+The executor, :meth:`repro.snn.distributed.DistributedSNN`
+(``exchange='ragged'``), runs the plan's rounds, pairs and bridges, but
+moves each pair's columns as a **block**: the sending group's whole
+``f32[R·B]`` spike block times a 0/1 column mask that keeps exactly the
+pair's consumed columns (:meth:`RaggedPlan.col_masks`), written into the
+receiver's buffer row whole.  The masked-out columns are those no stored
+tile of the receiver reads, so the receiver's block buffer is the one the
+packed payload would fill, bit for bit, and it ships
+:attr:`RaggedPlan.block_bytes_per_step`.  On a TPU the block is the
+cheaper of the two at every width: level 2 alone on a v5e 2×2 host
+(R·B = 8,192, one round) took 79.3, 84.3 and 192.8 µs a step packed
+against 6.72, 6.71 and 6.71 µs as a block at K_r = 128, 512 and 7,338.
+A packed round costs ``a + K_r · c``: c = 15.7 ns a lane, gathered on
+the sending side and scattered on the receiving one on the chip's
+serial path, and a = 77 µs for the scatter that lands the local group
+block beside the payloads.  A block round costs d = 6.7 µs at any
+width, the R·B lanes' multiply, ``ppermute``, ``psum`` and row write;
+d < a, so no width makes packing pay there.
+:func:`repro.snn.sparse.exchange_volume` reports the packed bytes next
+to the flat and sparse schedules.
 """
 from __future__ import annotations
 
@@ -72,7 +91,7 @@ class RaggedRound:
 
     @property
     def nbytes(self) -> int:
-        """Slow-axis bytes this round moves per simulation step."""
+        """Packed slow-axis bytes of this round per simulation step."""
         return len(self.pairs) * self.width * 4
 
 
@@ -96,9 +115,16 @@ class RaggedPlan:
 
     @property
     def bytes_per_step(self) -> int:
-        """Executed slow-axis bytes per step — padding included, so this
-        matches the ``ppermute`` payloads bit for bit."""
+        """Packed slow-axis bytes per step — padding included, so this
+        matches :meth:`round_messages` bit for bit."""
         return sum(rnd.nbytes for rnd in self.rounds)
+
+    @property
+    def block_bytes_per_step(self) -> int:
+        """Slow-axis bytes per step the executor moves: one masked
+        ``f32[R·B]`` group block per scheduled pair (module docstring)."""
+        r = self.mesh_shape[1]
+        return sum(len(rnd.pairs) for rnd in self.rounds) * r * self.block_size * 4
 
     @property
     def packed_bytes_per_step(self) -> int:
@@ -106,13 +132,29 @@ class RaggedPlan:
         static-shape constraint pads up from)."""
         return sum(4 * int(cols.size) for cols in self.pair_cols.values())
 
-    def round_messages(self) -> list[list[tuple[int, int, int]]]:
-        """Flat-device ``(src, dst, nbytes)`` triples per executed round.
+    def col_masks(self) -> tuple[np.ndarray, ...]:
+        """The executor's input of each live round: ``f32[n_dev, R·B]``,
+        per device, 1 on the columns of its group block that the round's
+        pair from its group consumes (``pair_cols``, on every member of a
+        sending group) and 0 elsewhere."""
+        r = self.mesh_shape[1]
+        masks = []
+        for rnd in self.rounds:
+            if not rnd.pairs:
+                continue
+            mask = np.zeros((self.n_devices, r * self.block_size), np.float32)
+            for gs, gd in rnd.pairs:
+                mask[gs * r : (gs + 1) * r, self.pair_cols[(gs, gd)]] = 1.0
+            masks.append(mask)
+        return tuple(masks)
 
-        The wire-level view of the plan: one padded ``K_r · 4``-byte
+    def round_messages(self) -> list[list[tuple[int, int, int]]]:
+        """Flat-device ``(src, dst, nbytes)`` triples per round.
+
+        The wire-level view of the packed plan: one padded ``K_r · 4``-byte
         payload per ``(bridge, bridge)`` pair of each round's joint-axis
-        ``ppermute`` — exactly what the ragged executor moves, so the
-        total equals :attr:`bytes_per_step` (padding included).  This is
+        ``ppermute``, so the total equals :attr:`bytes_per_step` (padding
+        included).  This is
         the replay input :mod:`repro.netsim` pins its byte accounting
         against ``exchange_volume(..., plan=...)['ragged']`` with.
         """

@@ -257,10 +257,13 @@ def exchange_volume(
 
     When ``plan`` (a :class:`repro.snn.ragged.RaggedPlan` for the same
     mask and mesh) is given, the result gains a ``'ragged'`` entry:
-    the bridge-compacted, column-pruned payload bytes the ragged executor
-    moves — per round, ``|pairs_r| · K_r · 4`` with ``K_r`` the padded
-    payload width, so the accounting matches the executed ``ppermute``
-    schedule exactly (padding included).
+    the bridge-compacted, column-pruned payload bytes of the plan's
+    rounds — per round, ``|pairs_r| · K_r · 4`` with ``K_r`` the padded
+    payload width, so the accounting matches
+    :meth:`~repro.snn.ragged.RaggedPlan.round_messages` exactly (padding
+    included): what a fabric priced by the byte carries.  The executor
+    ships one masked ``R·B`` group block a pair instead
+    (:attr:`~repro.snn.ragged.RaggedPlan.block_bytes_per_step`).
     """
     n = int(mask.shape[0])
     if mesh_shape is None or len(mesh_shape) == 1:

@@ -79,6 +79,7 @@ print("OK")
     out = run_devices(code, n_devices=n_devices)
     assert "platform=cpu" in out and f"devices={n_devices}" in out
     assert "weight strips a step" in out
+    assert ("level-2 rounds: block=" in out) == (n_devices > 1)
     assert "OK" in out
 
 

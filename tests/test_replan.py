@@ -436,6 +436,84 @@ print("OK")
 """
         assert "OK" in run_devices(code, n_devices=32)
 
+    def test_new_columns_reuse_the_compiled_step(self):
+        """Every round moves ``R·B`` lanes a pair, so a flip to a plan of
+        the same rounds with new consumed columns — and another widest
+        pair ``K_r`` — keeps the step signature: the column masks are jit
+        inputs, the swap reuses the compiled step and replays the
+        from-scratch engine bit for bit."""
+        code = """
+import numpy as np, jax
+import repro.snn.distributed as dist_mod
+from repro.snn import DistributedSNN, LIFParams, BlockSynapses, PlanBuffer
+from repro.snn.ragged import build_ragged_plan
+from jax.sharding import AxisType
+from tests.test_snn_sparse import _clustered_w
+
+params = LIFParams(noise_sigma=1.0)
+key = jax.random.PRNGKey(3)
+w = _clustered_w(256, 4, extra=((0, 2),)) * 0.25
+mesh = jax.make_mesh((2, 2), ("pod", "data"), axis_types=(AxisType.Auto,) * 2)
+eng = DistributedSNN(mesh=mesh, params=params, exchange="ragged", i_ext=1.5,
+                     syn=BlockSynapses.from_dense(w, 4))
+buf = PlanBuffer(eng)
+plan = buf.engine.plan
+r1 = np.asarray(buf.engine.run(200, key=key))
+
+w2 = w.copy()
+w2[:32, 128:] = w2[128:160, :128] = 0.0  # 32 consumed columns of each pair go
+syn2 = BlockSynapses.from_dense(w2, 4)
+plan2 = build_ragged_plan(syn2, (2, 2))
+(rnd,), (rnd2,) = ([x for x in p.rounds if x.pairs] for p in (plan, plan2))
+assert rnd2.width < rnd.width and (rnd2.shift, rnd2.perm) == (rnd.shift, rnd.perm)
+info0 = dist_mod._sparse_step.cache_info()
+assert buf.stage(plan2, syn=syn2)
+r2 = np.asarray(buf.flip().run(200, key=key))
+info1 = dist_mod._sparse_step.cache_info()
+assert info1.misses == info0.misses and info1.hits > info0.hits
+fresh = DistributedSNN(mesh=mesh, params=params, exchange="ragged", i_ext=1.5, syn=syn2)
+assert np.array_equal(r2, np.asarray(fresh.run(200, key=key)))
+assert not np.array_equal(r2, r1)
+print("OK")
+"""
+        assert "OK" in run_devices(code, n_devices=4)
+
+
+    def test_new_shift_recompiles(self):
+        """A plan that adds a live round (another ring shift) changes the
+        step signature: the flip compiles a new step, which replays the
+        from-scratch engine bit for bit."""
+        code = """
+import numpy as np, jax
+import repro.snn.distributed as dist_mod
+from repro.snn import DistributedSNN, LIFParams, BlockSynapses, PlanBuffer
+from repro.snn.ragged import build_ragged_plan
+from jax.sharding import AxisType
+from tests.test_snn_sparse import _clustered_w
+
+params = LIFParams(noise_sigma=1.0)
+key = jax.random.PRNGKey(5)
+mesh = jax.make_mesh((4, 2), ("pod", "data"), axis_types=(AxisType.Auto,) * 2)
+w = _clustered_w(256, 8, extra=((0, 2),)) * 0.25  # group shift 1 only
+eng = DistributedSNN(mesh=mesh, params=params, exchange="ragged", i_ext=1.5,
+                     syn=BlockSynapses.from_dense(w, 8))
+buf = PlanBuffer(eng)
+buf.engine.run(50, key=key)
+w2 = _clustered_w(256, 8, extra=((0, 2), (0, 4))) * 0.25  # shifts 1 and 2
+syn2 = BlockSynapses.from_dense(w2, 8)
+plan2 = build_ragged_plan(syn2, (4, 2))
+shifts = [[rnd.shift for rnd in p.rounds if rnd.pairs] for p in (buf.engine.plan, plan2)]
+assert shifts == [[1], [1, 2]], shifts
+misses = dist_mod._sparse_step.cache_info().misses
+assert not buf.stage(plan2, syn=syn2)
+r2 = np.asarray(buf.flip().run(50, key=key))
+assert dist_mod._sparse_step.cache_info().misses == misses + 1
+fresh = DistributedSNN(mesh=mesh, params=params, exchange="ragged", i_ext=1.5, syn=syn2)
+assert np.array_equal(r2, np.asarray(fresh.run(50, key=key)))
+print("OK")
+"""
+        assert "OK" in run_devices(code, n_devices=8)
+
 
 class TestBatchEvacuation:
     def test_batch_matches_sequential_singles(self):

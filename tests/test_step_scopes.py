@@ -131,6 +131,31 @@ def test_setup_spans_and_exchange_bytes_counter():
     assert counter["args"] == {"level2": float(eng.exchange_stats()[eng.exchange])}
 
 
+def test_level2_rounds_counter_on_four_devices():
+    """On a 2×2 mesh the compile also records the ragged plan's live
+    rounds, next to the bytes they move: one masked ``R·B``-lane group
+    block a scheduled pair, more than the plan's packed payloads."""
+    code = """
+import json
+from repro import obs
+from repro.launch import run_brainsim
+eng = run_brainsim.build_engine(32, 4)
+obs.enable()
+eng.compile(4)
+plan = eng._ragged_plan()
+counters = {e["name"]: e["args"] for e in obs.events() if e["ph"] == "C"}
+print(json.dumps([counters, plan.bytes_per_step, 2 * 2 * eng.syn.block_size * 4]))
+"""
+    import json
+
+    line = run_devices(code, n_devices=4).strip().splitlines()[-1]
+    counters, packed, two_blocks = json.loads(line)
+    assert list(counters) == ["snn.exchange_bytes", "snn.level2_rounds"]
+    assert counters["snn.level2_rounds"] == {"block": 1.0}
+    assert counters["snn.exchange_bytes"] == {"level2": float(two_blocks)}
+    assert packed < two_blocks
+
+
 def test_spans_land_in_the_profile(tmp_path):
     """Under ``jax.profiler.trace`` the tracer's spans are on the profile's
     host plane, on the same clock as the rest of the profile."""

@@ -78,7 +78,6 @@ def test_sparse_step_compiles_with_kernel(topo):
             params=LIFParams(),
             policy=KernelPolicy(use_pallas=True),
             i_ext=4.0,
-            ragged_scatter="fused",
             n_steps=n_steps,
             signature=("sparse", ()),
         )
@@ -96,16 +95,19 @@ def test_sparse_step_compiles_with_kernel(topo):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_ragged_step_for_v5e_carries_the_scopes(topo):
-    """The 2×2 ragged step as the chip compiles it: every named scope
-    reaches the ``op_name`` metadata, the Pallas kernel keeps its name
-    under ``accumulate``, and the collective-permute and all-reduce sit
-    under ``exchange/level2/send``."""
+@pytest.mark.parametrize("b", [128, 4096])
+def test_ragged_step_for_v5e_carries_the_scopes(topo, b):
+    """The 2×2 ragged step as the chip compiles it, at a small block and
+    at the 2×2 cell's (B = 4,096, so R·B = 8,192 lanes a round): every
+    named scope reaches the ``op_name`` metadata, the Pallas kernel keeps
+    its name under ``accumulate``, the collective-permute and all-reduce
+    sit under ``exchange/level2/send``, and level 2 holds no gather and
+    no scatter (the masked block lands by a row write)."""
     import re
 
     from repro.snn.distributed import STEP_SCOPES
 
-    b, k, width = 128, 4, 181
+    k = 4
     mesh = Mesh(np.asarray(topo.devices[:4]).reshape(2, 2), ("pod", "data"))
     fn = _sparse_step(
         _StepKey(
@@ -113,9 +115,8 @@ def test_ragged_step_for_v5e_carries_the_scopes(topo):
             params=LIFParams(noise_sigma=1.0),
             policy=KernelPolicy(use_pallas=True),
             i_ext=1.0,
-            ragged_scatter="fused",
             n_steps=4,
-            signature=("ragged", ((1, width, ((1, 2), (2, 1))),)),
+            signature=("ragged", ((1, ((1, 2), (2, 1))),)),
         )
     )
     sh = NamedSharding(mesh, P(("pod", "data")))
@@ -126,7 +127,7 @@ def test_ragged_step_for_v5e_carries_the_scopes(topo):
         _sds(key.shape, key.dtype, sh),
         _sds((4, k), jnp.int32, sh),
         _sds((4, k, b, b), jnp.float32, sh),
-        (_sds((4, 2, width), jnp.int32, sh),),
+        (_sds((4, 2 * b), jnp.float32, sh),),
     ).compile().as_text()
     ops = re.findall(r'^\s*(?:ROOT )?%?([\w.\-]+) = .*? ([a-z][a-z0-9\-]*)\(.*?op_name="([^"]*)"',
                      text, re.M)
@@ -138,3 +139,5 @@ def test_ragged_step_for_v5e_carries_the_scopes(topo):
     assert kernel and all("/accumulate/" in p for p in kernel)
     sends = [p for _, kind, p in ops if kind.startswith(("collective-permute", "all-reduce"))]
     assert sends and all("/exchange/level2/send/" in p for p in sends)
+    moves = {op for _, op, p in ops if "/exchange/level2/" in p and op in ("gather", "scatter")}
+    assert not moves
